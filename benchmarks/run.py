@@ -12,6 +12,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (behavioral, case_study, kernel_bench, latency,
                             pem_snapshot, scaling)
 

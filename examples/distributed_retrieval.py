@@ -10,6 +10,7 @@ this the §Perf "flexvec-1" iteration.
 
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # 8 host devices, never a TPU
 
 import time
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.data.loader import LMDataConfig, SyntheticLMStream
 from repro.dist.sharding import default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.layers import LMConfig
 from repro.train.loop import TrainLoopConfig, Trainer
@@ -42,7 +43,7 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = build(args.params)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
     print(f"== {cfg.name}: {cfg.n_params/1e6:.1f}M params, "
           f"{args.steps} steps, batch {args.batch} x seq {args.seq}")

@@ -21,6 +21,7 @@ from jax.sharding import Mesh
 from repro.configs.base import ArchSpec, LoweredSpec, ShapeCell, with_sharding
 from repro.dist.sharding import ShardingRules, default_rules
 from repro.kernels.mmr.ref import mmr_ref
+from repro.launch.mesh import make_local_mesh
 
 SHAPES = {
     "corpus_240k": dict(n=240_000, batch=64, pool=500, over=1500),
@@ -156,7 +157,7 @@ class FlexvecArch(ArchSpec):
                            static_desc=f"flexvec/{shape}")
 
     def smoke_run(self) -> Dict[str, Any]:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         rules = default_rules(mesh)
         with mesh:
             k1, k2, k3 = jax.random.split(jax.random.key(0), 3)

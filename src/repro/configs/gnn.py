@@ -27,6 +27,7 @@ from repro.data.graph import (
     sample_subgraph,
 )
 from repro.dist.sharding import ShardingRules, default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import pna
 from repro.train.optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
 import numpy as np
@@ -141,7 +142,7 @@ class PNAArch(ArchSpec):
                            static_desc=f"pna/{shape}")
 
     def smoke_run(self) -> Dict[str, Any]:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         rules = default_rules(mesh)
         out: Dict[str, Any] = {}
         with mesh:

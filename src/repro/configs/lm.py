@@ -21,6 +21,7 @@ from jax.sharding import Mesh
 
 from repro.configs.base import ArchSpec, LoweredSpec, ShapeCell, with_sharding
 from repro.dist.sharding import ShardingRules, default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.layers import LMConfig, MoEConfig
 from repro.train.optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
@@ -174,7 +175,7 @@ class LMArch(ArchSpec):
 
     def smoke_run(self) -> Dict[str, Any]:
         cfg = self.smoke_cfg
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         rules = default_rules(mesh)
         with mesh:
             params = T.init_params(cfg, jax.random.key(0))

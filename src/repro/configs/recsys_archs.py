@@ -24,6 +24,7 @@ from repro.data import recsys as RD
 from repro.data.recsys import CRITEO_1TB_VOCAB_SIZES
 from repro.dist.sharding import ShardingRules, constrain, default_rules
 from repro.kernels.mmr.ref import mmr_ref
+from repro.launch.mesh import make_local_mesh
 from repro.models import recsys as R
 from repro.train.optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
 
@@ -159,7 +160,7 @@ class RecsysArch(ArchSpec):
                            static_desc=f"{self.arch_id}/retrieval_cand")
 
     def smoke_run(self) -> Dict[str, Any]:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         rules = default_rules(mesh)
         cfg = self.smoke_cfg
         with mesh:

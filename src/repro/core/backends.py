@@ -36,8 +36,9 @@ Registered backends:
     reference-numpy  paper-faithful, one matvec per direction (Table 1)
     fused-numpy      folded two-matvec formulation (one corpus stream)
     jit-jax          fused formulation jitted through XLA + device top-k
-    pallas           fused TPU kernel -> topk kernel (two launches, no
-                     host hop between score and select)
+    pallas           fused TPU scoring kernel -> jax.lax.top_k on the
+                     device-resident panel (no host hop between score
+                     and select)
     sharded          shard_map row-sharded scoring, shard-local top-k +
                      union merge (repro.dist.pem_sharded contract)
 
@@ -191,6 +192,10 @@ class FusedCounters:
         }
 
 
+# every device matmul runs at full f32 precision: a TPU's default rounds
+# f32 operands to bf16, which would change rankings, not just speed them up
+_HIGHEST = "highest"
+
 # -1e30 stands in for -inf inside traced MMR bodies (0 * -inf is NaN; the
 # kernels/mmr chain uses the same sentinel, see kernels/mmr/kernel.py NEG)
 _MMR_NEG = -1e30
@@ -222,7 +227,7 @@ def _device_mmr_trace(emb, rel, lams, pool_w, k: int):
     # precompute the pool gram matrix ONCE: the loop body then gathers a
     # row of S instead of running two (W, d) einsums per pick — one big
     # matmul replaces 2k tiny ones (>20x on the k=500 headline pool)
-    S = jnp.einsum("bwd,bvd->bwv", emb, emb)
+    S = jnp.einsum("bwd,bvd->bwv", emb, emb, precision=_HIGHEST)
 
     def body(i, carry):
         max_sim, taken, out = carry
@@ -456,6 +461,13 @@ class _DeviceMatrixMixin:
     dev_hits = 0       # calls served from the resident cache
     dev_evictions = 0  # LRU evictions
 
+    def _place(self, mat: np.ndarray):
+        """Host -> device copy of one (padded) corpus matrix; the sharded
+        backend overrides this to place rows across its mesh."""
+        import jax
+
+        return jax.device_put(mat)
+
     def _device_matrix(self, matrix: np.ndarray, pad: int = 0):
         cache: "OrderedDict[Tuple[int, int], Tuple[np.ndarray, object]]"
         cache = self.__dict__.setdefault("_dev_cache", OrderedDict())
@@ -466,12 +478,10 @@ class _DeviceMatrixMixin:
             cache.move_to_end(key)
             self.dev_hits += 1
             return entry[1]
-        import jax.numpy as jnp
-
         mat = np.asarray(matrix, np.float32)
         if pad:
             mat = np.pad(mat, ((0, pad), (0, 0)))
-        dev = jnp.asarray(mat)
+        dev = self._place(mat)
         cache[key] = (matrix, dev)
         cache.move_to_end(key)
         self.uploads += 1
@@ -810,11 +820,13 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
 
     def _build(self):
         import jax
+        import jax.numpy as jnp
 
         @jax.jit
         def fused(matrix, q_pre, q_sup, days, half_lives):
             decay = 1.0 / (1.0 + days[:, None] / half_lives[None, :])
-            return decay * (matrix @ q_pre) + matrix @ q_sup
+            return (decay * jnp.dot(matrix, q_pre, precision=_HIGHEST)
+                    + jnp.dot(matrix, q_sup, precision=_HIGHEST))
 
         return fused
 
@@ -827,13 +839,13 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         def fused_select(matrix, q_pre, q_sup, days, half_lives, mask,
                          lams, pool_w, bias):
             cache.jax_traces += 1  # python body runs only while tracing
-            scores = matrix @ q_pre
+            scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
             if structure.has_decay:
                 scores = scores * (
                     1.0 / (1.0 + days[:, None] / half_lives[None, :])
                 )
             if structure.suppress_bucket:
-                scores = scores + matrix @ q_sup
+                scores = scores + jnp.dot(matrix, q_sup, precision=_HIGHEST)
             if structure.bias:
                 # hybrid lexical leg: additive fusion before mask/top-k
                 scores = scores + bias
@@ -911,29 +923,30 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
 
 
 class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
-    """The fused TPU kernels (``repro.kernels.pem_score`` + ``topk`` +
-    ``mmr``).
+    """The fused TPU kernels (``repro.kernels.pem_score`` + ``mmr``).
 
-    Off-TPU the kernels run in Pallas interpret mode (the same path the
-    kernel tests validate).  The scoring kernel takes one decay column per
-    call, so requests group by half-life and each group scores in one
-    kernel launch; :meth:`score_select` keeps the score panel device-
-    resident and feeds it straight into the streaming top-k kernel, then
-    chains the ``kernels/mmr`` selection kernel for diverse plans — no
-    host hop anywhere in the chain, only final candidates come back.
+    The scoring kernel takes one decay column per call, so requests group
+    by half-life and each group scores in one kernel launch;
+    :meth:`score_select` keeps the score panel device-resident and selects
+    with ``jax.lax.top_k`` on it, then chains the ``kernels/mmr``
+    selection kernel for diverse plans — no host hop anywhere in the
+    chain, only final candidates come back.
     """
 
     name = "pallas"
+    #: Pallas interpret mode — the one switch for every kernel this backend
+    #: launches.  The CPU test suite turns it on (tests/conftest.py); the
+    #: served path leaves it off, so on a TPU the kernels always compile and
+    #: any other platform fails loudly (``repro.kernels.check_interpret``).
+    interpret: bool = False
 
     def _grouped_panel(self, matrix, days_ago, plans):
         """Device-resident (N, B) score panel, columns in plan order."""
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels.pem_score.ops import pem_score
 
         q_pre, q_sup = M.fold_plans(plans)
-        interpret = jax.default_backend() != "tpu"
         mat = self._device_matrix(matrix)
 
         groups: Dict[Optional[float], List[int]] = {}
@@ -952,27 +965,25 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                 jnp.asarray(q_pre[:, cols]),
                 jnp.asarray(q_sup[:, cols]),
                 decay,
-                interpret=interpret,
+                interpret=self.interpret,
             ))
             order.extend(cols)
         panel = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
         if order != list(range(len(plans))):
             panel = panel[:, np.argsort(np.asarray(order))]
-        return panel, interpret
+        return panel
 
     def score_panel(self, matrix, days_ago, plans):
         for p in plans:
             _require_days(p, days_ago)
-        panel, _ = self._grouped_panel(matrix, days_ago, plans)
-        return np.asarray(panel)
+        return np.asarray(self._grouped_panel(matrix, days_ago, plans))
 
     def score_select(self, matrix, days_ago, plans, ks, *, mask=None,
                      fused_mmr=None, score_bias=None, cohort=False):
         # the kernels take exact shapes (no executable cache keyed on
         # batch), so the cohort flag has nothing to bucket here
+        import jax
         import jax.numpy as jnp
-
-        from repro.kernels.topk.ops import topk
 
         for p in plans:
             _require_days(p, days_ago)
@@ -984,7 +995,7 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         # (clamped to the real row count: the kernels take exact shapes,
         # there is no compiled-executable cache to bucket rows for)
         w_stat = min(PlanStructure.of(plans, widths, n).width, n)
-        panel, interpret = self._grouped_panel(matrix, days_ago, plans)
+        panel = self._grouped_panel(matrix, days_ago, plans)
         if score_bias is not None:
             # hybrid lexical leg: additive fusion on the device-resident
             # panel, before mask/top-k (matches the jitted fused graphs)
@@ -992,11 +1003,11 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
             panel = panel + (b if b.ndim == 2 else b[:, None])
         if mask is not None:
             # tombstones (or each plan's candidate-panel column) drop out
-            # on device, before the top-k kernel
+            # on device, before selection
             m = jnp.asarray(mask)
             panel = jnp.where(m if m.ndim == 2 else m[:, None],
                               panel, -jnp.inf)
-        v, i = topk(panel.T, w_stat, interpret=interpret)
+        v, i = jax.lax.top_k(panel.T, w_stat)
         if not self._use_mmr(plans, fused_mmr):
             return _slice_candidates(i, v, widths)
         # fused diverse tail: the kernels/mmr pallas kernel selects over
@@ -1017,7 +1028,8 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                 continue
             pool_i = i[j, :pw]
             sel, _ = mmr_select(mat[pool_i][None], v[j, :pw][None], kf,
-                                float(p.diverse.lam), interpret=interpret)
+                                float(p.diverse.lam),
+                                interpret=self.interpret)
             out[j] = (np.asarray(jnp.take(pool_i, sel[0])).astype(np.int64),
                       np.asarray(jnp.take(v[j, :pw], sel[0])))
         return out
@@ -1026,7 +1038,6 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         """Merged-pool MMR through the ``kernels/mmr`` pallas kernel
         (pool pow2-bucketed with NEG-masked padding so the kernel compiles
         a bounded set of shapes)."""
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels.mmr.kernel import NEG
@@ -1043,8 +1054,7 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         rel = np.full(bucket, NEG, np.float32)
         rel[:pool] = vals
         sel, _ = mmr_select(emb[None], jnp.asarray(rel)[None], k,
-                            float(lam),
-                            interpret=jax.default_backend() != "tpu")
+                            float(lam), interpret=self.interpret)
         return np.asarray(sel)[0].astype(np.int64)
 
     def mmr_pool_segments_batch(self, segments, pools, ks, lams):
@@ -1072,55 +1082,77 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
 
     def __init__(self) -> None:
         self._fn = None
-        self._n_shards = None
+        self._shards_mesh = None
         self.plan_cache = PlanCache(self._build_select)
+
+    def _mesh(self):
+        """The 1-D ``shards`` mesh over every local device (built once)."""
+        if self._shards_mesh is None:
+            import jax
+            from jax.sharding import AxisType
+
+            self._shards_mesh = jax.make_mesh(
+                (len(jax.devices()),), ("shards",),
+                axis_types=(AxisType.Auto,))
+        return self._shards_mesh
+
+    def _place(self, mat: np.ndarray):
+        """Upload the corpus row-sharded over the mesh, so each device
+        holds its own 1/shards of the rows and ``shard_map`` never
+        reshards it per call.  Row sharding needs a shard multiple: the
+        scoring paths pad to one already, and any extra zero rows here sit
+        past the true row count, where nothing indexes."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh = self._mesh()
+        extra = (-mat.shape[0]) % mesh.size
+        if extra:
+            mat = np.pad(mat, ((0, extra), (0, 0)))
+        return jax.device_put(mat, NamedSharding(mesh, P("shards", None)))
 
     def _build(self):
         import jax
-        from jax.experimental.shard_map import shard_map
+        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-
-        n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev,), ("shards",))
 
         def local(matrix, q_pre, q_sup, days, half_lives):
             decay = 1.0 / (1.0 + days[:, None] / half_lives[None, :])
-            return decay * (matrix @ q_pre) + matrix @ q_sup
+            return (decay * jnp.dot(matrix, q_pre, precision=_HIGHEST)
+                    + jnp.dot(matrix, q_sup, precision=_HIGHEST))
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
-            mesh=mesh,
+            mesh=self._mesh(),
             in_specs=(P("shards", None), P(None, None), P(None, None),
                       P("shards"), P(None)),
             out_specs=P("shards", None),
-            check_rep=False,
+            check_vma=False,
         )
-        return jax.jit(fn), n_dev
+        return jax.jit(fn)
 
     def _build_select(self, structure: PlanStructure):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.dist.pem_sharded import (union_merge_topk,
                                             union_merge_topk_payload)
 
-        n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev,), ("shards",))
+        mesh = self._mesh()
         cache = self.plan_cache
 
         def local(matrix, q_pre, q_sup, days, half_lives, mask, bias):
             cache.jax_traces += 1  # python body runs only while tracing
             n_local = matrix.shape[0]
             shard = jax.lax.axis_index("shards")
-            scores = matrix @ q_pre
+            scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
             if structure.has_decay:
                 scores = scores * (
                     1.0 / (1.0 + days[:, None] / half_lives[None, :])
                 )
             if structure.suppress_bucket:
-                scores = scores + matrix @ q_sup
+                scores = scores + jnp.dot(matrix, q_sup, precision=_HIGHEST)
             if structure.bias:
                 # hybrid lexical leg, sharded row-wise like the mask
                 scores = scores + bias
@@ -1144,7 +1176,7 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
 
         out_specs = ((P(None, None), P(None, None), P(None, None, None))
                      if structure.mmr_k else (P(None, None), P(None, None)))
-        inner = shard_map(
+        inner = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P("shards", None), P(None, None), P(None, None),
@@ -1152,7 +1184,7 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                       P("shards", None) if structure.panel else P("shards"),
                       P("shards", None) if structure.bias else P(None, None)),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
         def fused_select(matrix, q_pre, q_sup, days, half_lives, mask,
@@ -1183,16 +1215,12 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         for p in plans:
             _require_days(p, days_ago)
         if self._fn is None:
-            # other threads key on _fn: set _n_shards FIRST so no caller can
-            # observe _fn non-None with _n_shards still unset
-            fn, n_shards = self._build()
-            self._n_shards = n_shards
-            self._fn = fn
+            self._fn = self._build()
         q_pre, q_sup = M.fold_plans(plans)
         n = matrix.shape[0]
         days = _days_f32(days_ago, n)
         # pad the row grid to the shard count, slice the panel back
-        pad = (-n) % self._n_shards
+        pad = (-n) % self._mesh().size
         mat = self._device_matrix(matrix, pad)
         if pad:
             days = np.pad(days, (0, pad))
@@ -1201,14 +1229,12 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
 
     def score_select(self, matrix, days_ago, plans, ks, *, mask=None,
                      fused_mmr=None, score_bias=None, cohort=False):
-        import jax
-
         for p in plans:
             _require_days(p, days_ago)
         n = matrix.shape[0]
         if n == 0:
             return [_empty_candidates() for _ in plans]
-        n_shards = len(jax.devices())
+        n_shards = self._mesh().size
         widths = [selection_width(p, k, n) for p, k in zip(plans, ks)]
         use_mmr = self._use_mmr(plans, fused_mmr)
         panel2d = mask is not None and mask.ndim == 2

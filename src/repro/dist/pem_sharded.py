@@ -19,7 +19,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.modulations import DEFAULT_DECAY_HALF_LIFE
@@ -153,11 +152,11 @@ def make_pem_topk(mesh: Mesh, rules: ShardingRules, k: int, raw: bool = False,
         return union_merge_topk(v, gi, axes, k)
 
     corpus_axes = axes if axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         sharded_topk,
         mesh=mesh,
         in_specs=(P(corpus_axes, None), P(corpus_axes), P(None, None), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn if raw else jax.jit(fn)

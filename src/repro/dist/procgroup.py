@@ -16,7 +16,7 @@ Design:
   mask panels, hybrid score bias) over ITS rows only, returning per-plan
   top-``width`` candidates in chunk-id space (plus pool embeddings for
   diverse plans).  Workers never import jax — the fused-numpy backend is
-  pure BLAS, so a forked worker starts in milliseconds.
+  pure BLAS.
 * ``dtype="f32b"`` workers score simple (no-filter, no-lexical) plans
   with a BLOCKED single-stream pass: cache-sized f32 row blocks hit one
   fused ``(d, 2B)`` query panel GEMM, so the corpus streams from RAM
@@ -62,7 +62,7 @@ path.
 Transports: ``inline`` (serial in-process calls — the deterministic
 default for tests), ``thread`` (one fan-out thread per replica; BLAS
 releases the GIL, so shards genuinely overlap and nothing is copied),
-``process`` (one OS process per replica, fork-preferred, length-prefixed
+``process`` (one OS process per replica, spawned, length-prefixed
 pickle over a ``multiprocessing.Pipe``).  The merge math is transport-
 independent; parity suites run the same cases across all three.
 """
@@ -531,14 +531,16 @@ def _worker_loop(conn, shard_id: int, replica: int, dim: int,
 
 
 class _ProcessClient:
-    """One OS-process replica behind a Pipe (fork-preferred: the corpus
-    arrays and imported modules are shared copy-on-write at start)."""
+    """One OS-process replica behind a Pipe.
+
+    Workers are spawned, never forked: the coordinator may have brought up
+    a JAX backend (a TPU runtime, its threads), and a forked copy of that
+    state is unsafe.  A spawned worker starts from a fresh import and gets
+    its rows over the pipe like any other mutation."""
 
     def __init__(self, shard_id: int, replica: int, dim: int,
                  opts: Dict[str, Any]) -> None:
-        method = ("fork" if "fork" in mp.get_all_start_methods()
-                  else mp.get_start_method(allow_none=False))
-        ctx = mp.get_context(method)
+        ctx = mp.get_context("spawn")
         self._conn, child = ctx.Pipe()
         self._proc = ctx.Process(
             target=_worker_loop, args=(child, shard_id, replica, dim, opts),

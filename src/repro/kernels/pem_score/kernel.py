@@ -9,7 +9,8 @@ TPU mapping (DESIGN.md §2.1):
 * corpus tile (BLOCK_N x d) streams HBM->VMEM exactly once per query block —
   vs the paper's numpy engine which re-reads M for every direction;
 * d = 128 Matryoshka dims align exactly with MXU lanes; both matmuls hit the
-  MXU with fp32 accumulation (``preferred_element_type``);
+  MXU at full f32 precision (``HIGHEST`` lowers to Mosaic's fp32 contract
+  precision, so scores match the f32 host oracle) with fp32 accumulation;
 * decay multiply + sum is a VPU epilogue fused in-register;
 * grid is fully parallel (no cross-block state).
 
@@ -26,17 +27,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels import check_interpret
 
 BLOCK_N = 1024   # corpus rows per tile (multiple of 8 sublanes)
 BLOCK_B = 128    # query columns per tile (multiple of 128 lanes)
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _pem_score_kernel(m_ref, qpre_ref, qsup_ref, decay_ref, out_ref):
     m = m_ref[...].astype(jnp.float32)                       # (bn, d)
-    pre = jnp.dot(m, qpre_ref[...].astype(jnp.float32),
+    pre = jnp.dot(m, qpre_ref[...].astype(jnp.float32), precision=_HI,
                   preferred_element_type=jnp.float32)        # (bn, bq) MXU
-    sup = jnp.dot(m, qsup_ref[...].astype(jnp.float32),
+    sup = jnp.dot(m, qsup_ref[...].astype(jnp.float32), precision=_HI,
                   preferred_element_type=jnp.float32)        # (bn, bq) MXU
     out_ref[...] = decay_ref[...] * pre + sup                # VPU epilogue
 
@@ -69,9 +71,9 @@ def pem_score_pallas(
         ],
         out_specs=pl.BlockSpec((block_n, block_b), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
+        interpret=check_interpret(interpret),
         name="pem_score",
     )(matrix, q_pre, q_sup, decay.reshape(n, 1).astype(jnp.float32))

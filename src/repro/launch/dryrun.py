@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # 512 host placeholders, never a TPU
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST precede any jax import: jax locks the device count
+The lines above MUST precede any jax import: jax locks the device count
 at first init, and the production meshes need 512 host placeholder devices.
 (Smoke tests and benches never import this module — they see 1 device.)
 

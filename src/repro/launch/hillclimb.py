@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # 512 host placeholders, never a TPU
 
 """Perf hillclimb driver (§Perf): the three chosen cells, one iteration per
 invocation step, each a (hypothesis -> change -> re-lower -> measure) cycle.

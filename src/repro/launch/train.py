@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import ASSIGNED, get_arch
 from repro.dist.sharding import default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.train.loop import TrainLoopConfig, Trainer
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
 
@@ -64,7 +65,7 @@ def main() -> None:
     args = ap.parse_args()
 
     arch = get_arch(args.arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
 
     if arch.family == "lm":

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist.sharding import default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.layers import LMConfig
 from repro.train.optimizer import (
@@ -19,7 +20,7 @@ def test_accum_matches_full_batch():
     cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                    head_dim=16, d_ff=64, vocab=64, dtype=jnp.float32,
                    q_chunk=16, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
     params = T.init_params(cfg, jax.random.key(0))
     ocfg = AdamWConfig(lr=1e-3, clip_norm=None, compress_grads=False)
@@ -47,7 +48,7 @@ def test_accum_trains():
     cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                    head_dim=16, d_ff=64, vocab=64, dtype=jnp.float32,
                    q_chunk=16, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
     params = T.init_params(cfg, jax.random.key(0))
     ocfg = AdamWConfig(lr=3e-3, warmup_steps=2, total_steps=30)

@@ -10,8 +10,6 @@ from repro.kernels.mmr.ops import mmr_select
 from repro.kernels.mmr.ref import mmr_ref
 from repro.kernels.pem_score.ops import pem_score
 from repro.kernels.pem_score.ref import pem_score_ref
-from repro.kernels.topk.ops import topk
-from repro.kernels.topk.ref import topk_ref
 
 RNG = np.random.default_rng(0)
 
@@ -43,27 +41,6 @@ def test_pem_score_no_decay():
     qs = jnp.zeros((128, 3), jnp.float32)
     out = pem_score(m, qp, qs, None, interpret=True, block_n=256)
     np.testing.assert_allclose(np.asarray(out), np.asarray(m @ qp), atol=1e-5)
-
-
-@pytest.mark.parametrize("n,k", [(1000, 1), (1000, 37), (5000, 500), (100, 100)])
-def test_topk_sweep(n, k):
-    s = jnp.asarray(RNG.standard_normal((4, n)).astype(np.float32))
-    vk, ik = topk(s, k, interpret=True, block_n=512)
-    vr, ir = topk_ref(s, k)
-    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
-    # indices may differ on exact ties; values above already assert equal
-    got = np.take_along_axis(np.asarray(s), np.asarray(ik), axis=1)
-    np.testing.assert_array_equal(got, np.asarray(vr))
-
-
-def test_topk_with_ties_and_negatives():
-    s = jnp.asarray(np.tile(np.array([-1.0, 3.0, 3.0, -5.0, 0.0], np.float32), (2, 40)))
-    vk, ik = topk(s, 10, interpret=True, block_n=128)
-    vr, _ = topk_ref(s, 10)
-    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
-    # no index returned twice
-    for row in np.asarray(ik):
-        assert len(set(row.tolist())) == len(row)
 
 
 @pytest.mark.parametrize("n,k,d", [(64, 8, 32), (200, 50, 128), (300, 17, 64)])

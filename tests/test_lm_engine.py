@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist.sharding import default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.layers import LMConfig
 from repro.serve.lm_engine import DecodeRequest, LMDecodeEngine
@@ -14,7 +15,7 @@ def _engine(n_slots=3, max_ctx=48):
     cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                    head_dim=16, d_ff=64, vocab=64, dtype=jnp.float32,
                    q_chunk=16, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
     params = T.init_params(cfg, jax.random.key(0))
     return mesh, cfg, rules, params, LMDecodeEngine(

@@ -10,6 +10,7 @@ import pytest
 
 from repro.data.loader import LMDataConfig, SyntheticLMStream
 from repro.dist.sharding import default_rules
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.layers import LMConfig
 from repro.train import checkpoint as C
@@ -19,7 +20,7 @@ from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
 
 
 def _setup(tmp_path=None, seed=0):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = default_rules(mesh)
     cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                    head_dim=16, d_ff=64, vocab=64, dtype=jnp.float32,
