@@ -78,6 +78,7 @@ from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
 import numpy as np
 
 from repro.core import modulations as M
+from repro.core.spans import RECORDER
 
 __all__ = [
     "ExecutionBackend",
@@ -176,20 +177,33 @@ class FusedCounters:
     :func:`mmr_host` oracle; a regression back to host MMR shows up here
     before it shows up as latency.  ``panel_batches`` counts batched
     (N, B) mask-panel passes that served a heterogeneous-filter cohort in
-    ONE device scoring pass instead of one per distinct filter.  Benign
-    int bumps, same convention as the store's counters.
+    ONE device scoring pass instead of one per distinct filter.
+    ``upload_bytes`` sums the host arrays every device ``score_select``
+    hands its compiled graph (``days``, the live mask or mask panel, the
+    bias panel, the query panels, and a corpus matrix not yet resident):
+    the per-query host-to-device traffic.  Benign int bumps, same
+    convention as the store's counters.
     """
 
     device_mmr: int = 0
     host_pool_transfers: int = 0
     panel_batches: int = 0
+    upload_bytes: int = 0
 
     def stats(self) -> Dict[str, int]:
         return {
             "device_mmr": self.device_mmr,
             "host_pool_transfers": self.host_pool_transfers,
             "panel_batches": self.panel_batches,
+            "upload_bytes": self.upload_bytes,
         }
+
+
+def _count_uploads(counters: Optional[FusedCounters], *arrays) -> None:
+    """Add the host arrays among ``arrays`` to ``counters.upload_bytes``."""
+    if counters is not None:
+        counters.upload_bytes += sum(a.nbytes for a in arrays
+                                     if isinstance(a, np.ndarray))
 
 
 # every device matmul runs at full f32 precision: a TPU's default rounds
@@ -393,7 +407,9 @@ class PlanCache:
 
     ``jax_traces`` is incremented from INSIDE the traced python bodies, so
     it counts real (re)traces, not just cache misses; tests use it to pin
-    the zero-retrace contract.
+    the zero-retrace contract.  With the span recorder on, the first call
+    of a freshly built executable (its trace and compile) records a
+    ``plan.compile`` span.
 
     The cache is bounded with LRU eviction at ``maxsize``: every hit
     refreshes the entry, so the hot segments' executables stay resident no
@@ -424,11 +440,25 @@ class PlanCache:
                 self.hits += 1
                 return fn
             self.builds += 1
-            fn = self._fns[structure] = self._builder(structure)
+            fn = self._fns[structure] = self._first_call(
+                structure, self._builder(structure))
             while len(self._fns) > self.maxsize:
                 self._fns.popitem(last=False)
                 self.evictions += 1
             return fn
+
+    def _first_call(self, structure: PlanStructure,
+                    fn: Callable) -> Callable:
+        """``fn`` behind a wrapper that spans its first call (the compile)
+        and then puts ``fn`` itself back in the cache."""
+        def first(*args):
+            with self._lock:
+                if self._fns.get(structure) is first:
+                    self._fns[structure] = fn
+            with RECORDER.span("plan.compile"):
+                return fn(*args)
+
+        return first
 
     def stats(self) -> Dict[str, int]:
         with self._lock:
@@ -468,7 +498,8 @@ class _DeviceMatrixMixin:
 
         return jax.device_put(mat)
 
-    def _device_matrix(self, matrix: np.ndarray, pad: int = 0):
+    def _device_matrix(self, matrix: np.ndarray, pad: int = 0,
+                       counters: Optional[FusedCounters] = None):
         cache: "OrderedDict[Tuple[int, int], Tuple[np.ndarray, object]]"
         cache = self.__dict__.setdefault("_dev_cache", OrderedDict())
         key = (id(matrix), pad)
@@ -482,6 +513,7 @@ class _DeviceMatrixMixin:
         if pad:
             mat = np.pad(mat, ((0, pad), (0, 0)))
         dev = self._place(mat)
+        _count_uploads(counters, mat)
         cache[key] = (matrix, dev)
         cache.move_to_end(key)
         self.uploads += 1
@@ -695,9 +727,14 @@ class ExecutionBackend:
         fused_mmr: Optional[bool] = None,
         score_bias: Optional[np.ndarray] = None,
         cohort: bool = False,
+        counters: Optional[FusedCounters] = None,
     ) -> List[Candidates]:
         """Fused score->select: per-plan ``(indices, scores)`` of the top
         ``selection_width(plan, k, N)`` candidates, descending by score.
+
+        ``counters`` receives the host-to-device bytes a device backend
+        hands its graph (``FusedCounters.upload_bytes``); the host path
+        uploads nothing.
 
         ``cohort=True`` marks a multi-query cohort call (several admitted
         queries folded into one panel): device backends pow2-bucket the
@@ -839,33 +876,40 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         def fused_select(matrix, q_pre, q_sup, days, half_lives, mask,
                          lams, pool_w, bias):
             cache.jax_traces += 1  # python body runs only while tracing
-            scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
-            if structure.has_decay:
-                scores = scores * (
-                    1.0 / (1.0 + days[:, None] / half_lives[None, :])
-                )
-            if structure.suppress_bucket:
-                scores = scores + jnp.dot(matrix, q_sup, precision=_HIGHEST)
-            if structure.bias:
-                # hybrid lexical leg: additive fusion before mask/top-k
-                scores = scores + bias
-            # one mask covers pow2 row padding AND segment tombstones; a
-            # panel structure carries one mask column PER PLAN instead
-            scores = jnp.where(mask if structure.panel else mask[:, None],
-                               scores, -jnp.inf)
-            v, i = jax.lax.top_k(scores.T, structure.width)  # (B, width)
+            # the scopes name the stages in the ops' metadata (what a
+            # device trace groups by); the computation is unchanged
+            with jax.named_scope("score"):
+                scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
+                if structure.has_decay:
+                    scores = scores * (
+                        1.0 / (1.0 + days[:, None] / half_lives[None, :])
+                    )
+                if structure.suppress_bucket:
+                    scores = scores + jnp.dot(matrix, q_sup,
+                                              precision=_HIGHEST)
+                if structure.bias:
+                    # hybrid lexical leg: additive fusion before mask/top-k
+                    scores = scores + bias
+                # one mask covers pow2 row padding AND segment tombstones;
+                # a panel structure carries one mask column PER PLAN
+                scores = jnp.where(mask if structure.panel
+                                   else mask[:, None], scores, -jnp.inf)
+            with jax.named_scope("select"):
+                v, i = jax.lax.top_k(scores.T, structure.width)  # (B, w)
             if structure.mmr_k:
                 # fused diverse tail: MMR over the (B, width) pool without
                 # leaving the graph (non-diverse columns ride along with
                 # lam=1.0, which IS top-k order); positions past each
                 # plan's true pool re-mask to -inf so downstream filters
                 # treat them exactly like unselected top-k padding
-                sel = _device_mmr_trace(matrix[i], v, lams, pool_w,
-                                        structure.mmr_k)
-                i = jnp.take_along_axis(i, sel, axis=1)
-                v = jnp.take_along_axis(v, sel, axis=1)
-                keep = jnp.arange(structure.mmr_k)[None, :] < pool_w[:, None]
-                v = jnp.where(keep, v, -jnp.inf)
+                with jax.named_scope("mmr"):
+                    sel = _device_mmr_trace(matrix[i], v, lams, pool_w,
+                                            structure.mmr_k)
+                    i = jnp.take_along_axis(i, sel, axis=1)
+                    v = jnp.take_along_axis(v, sel, axis=1)
+                    keep = (jnp.arange(structure.mmr_k)[None, :]
+                            < pool_w[:, None])
+                    v = jnp.where(keep, v, -jnp.inf)
             return i, v
 
         return jax.jit(fused_select)
@@ -883,7 +927,8 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         )
 
     def score_select(self, matrix, days_ago, plans, ks, *, mask=None,
-                     fused_mmr=None, score_bias=None, cohort=False):
+                     fused_mmr=None, score_bias=None, cohort=False,
+                     counters=None):
         for p in plans:
             _require_days(p, days_ago)
         n = matrix.shape[0]
@@ -913,8 +958,10 @@ class JitJaxBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         bias = (_expand_bias(score_bias, structure.n_rows, structure.batch,
                              len(plans))
                 if structure.bias else np.zeros((1, 1), np.float32))
-        idx, vals = fn(self._device_matrix(matrix, pad), q_pre, q_sup,
-                       days, half_lives, live, lams, pool_w, bias)
+        _count_uploads(counters, q_pre, q_sup, days, half_lives, live, lams,
+                       pool_w, bias)
+        idx, vals = fn(self._device_matrix(matrix, pad, counters), q_pre,
+                       q_sup, days, half_lives, live, lams, pool_w, bias)
         # with the fused MMR tail the device returns final-k blocks for
         # every plan (plain plans ride the lam=1.0 identity)
         out_w = ([min(max(k, 0), w) for k, w in zip(ks, widths)]
@@ -940,14 +987,14 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
     #: any other platform fails loudly (``repro.kernels.check_interpret``).
     interpret: bool = False
 
-    def _grouped_panel(self, matrix, days_ago, plans):
+    def _grouped_panel(self, matrix, days_ago, plans, counters=None):
         """Device-resident (N, B) score panel, columns in plan order."""
         import jax.numpy as jnp
 
         from repro.kernels.pem_score.ops import pem_score
 
         q_pre, q_sup = M.fold_plans(plans)
-        mat = self._device_matrix(matrix)
+        mat = self._device_matrix(matrix, counters=counters)
 
         groups: Dict[Optional[float], List[int]] = {}
         for j, plan in enumerate(plans):
@@ -958,13 +1005,15 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         order: List[int] = []
         for hl, cols in groups.items():
             decay = None
+            qp, qs = q_pre[:, cols], q_sup[:, cols]
             if hl is not None:
-                decay = jnp.asarray(_decay_column(days_ago, hl), jnp.float32)
+                decay = np.asarray(_decay_column(days_ago, hl), np.float32)
+            _count_uploads(counters, qp, qs, decay)
             parts.append(pem_score(
                 mat,
-                jnp.asarray(q_pre[:, cols]),
-                jnp.asarray(q_sup[:, cols]),
-                decay,
+                jnp.asarray(qp),
+                jnp.asarray(qs),
+                None if decay is None else jnp.asarray(decay),
                 interpret=self.interpret,
             ))
             order.extend(cols)
@@ -979,7 +1028,8 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         return np.asarray(self._grouped_panel(matrix, days_ago, plans))
 
     def score_select(self, matrix, days_ago, plans, ks, *, mask=None,
-                     fused_mmr=None, score_bias=None, cohort=False):
+                     fused_mmr=None, score_bias=None, cohort=False,
+                     counters=None):
         # the kernels take exact shapes (no executable cache keyed on
         # batch), so the cohort flag has nothing to bucket here
         import jax
@@ -995,19 +1045,25 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         # (clamped to the real row count: the kernels take exact shapes,
         # there is no compiled-executable cache to bucket rows for)
         w_stat = min(PlanStructure.of(plans, widths, n).width, n)
-        panel = self._grouped_panel(matrix, days_ago, plans)
-        if score_bias is not None:
-            # hybrid lexical leg: additive fusion on the device-resident
-            # panel, before mask/top-k (matches the jitted fused graphs)
-            b = jnp.asarray(np.asarray(score_bias, np.float32))
-            panel = panel + (b if b.ndim == 2 else b[:, None])
-        if mask is not None:
-            # tombstones (or each plan's candidate-panel column) drop out
-            # on device, before selection
-            m = jnp.asarray(mask)
-            panel = jnp.where(m if m.ndim == 2 else m[:, None],
-                              panel, -jnp.inf)
-        v, i = jax.lax.top_k(panel.T, w_stat)
+        # the same three stage scopes as the jitted graphs
+        with jax.named_scope("score"):
+            panel = self._grouped_panel(matrix, days_ago, plans, counters)
+            if score_bias is not None:
+                # hybrid lexical leg: additive fusion on the device-
+                # resident panel, before mask/top-k (as the jitted graphs)
+                b = np.asarray(score_bias, np.float32)
+                _count_uploads(counters, b)
+                b = jnp.asarray(b)
+                panel = panel + (b if b.ndim == 2 else b[:, None])
+            if mask is not None:
+                # tombstones (or each plan's candidate-panel column) drop
+                # out on device, before selection
+                _count_uploads(counters, np.asarray(mask))
+                m = jnp.asarray(mask)
+                panel = jnp.where(m if m.ndim == 2 else m[:, None],
+                                  panel, -jnp.inf)
+        with jax.named_scope("select"):
+            v, i = jax.lax.top_k(panel.T, w_stat)
         if not self._use_mmr(plans, fused_mmr):
             return _slice_candidates(i, v, widths)
         # fused diverse tail: the kernels/mmr pallas kernel selects over
@@ -1027,9 +1083,10 @@ class PallasBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                 out[j] = _empty_candidates()
                 continue
             pool_i = i[j, :pw]
-            sel, _ = mmr_select(mat[pool_i][None], v[j, :pw][None], kf,
-                                float(p.diverse.lam),
-                                interpret=self.interpret)
+            with jax.named_scope("mmr"):
+                sel, _ = mmr_select(mat[pool_i][None], v[j, :pw][None], kf,
+                                    float(p.diverse.lam),
+                                    interpret=self.interpret)
             out[j] = (np.asarray(jnp.take(pool_i, sel[0])).astype(np.int64),
                       np.asarray(jnp.take(v[j, :pw], sel[0])))
         return out
@@ -1146,33 +1203,36 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
             cache.jax_traces += 1  # python body runs only while tracing
             n_local = matrix.shape[0]
             shard = jax.lax.axis_index("shards")
-            scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
-            if structure.has_decay:
-                scores = scores * (
-                    1.0 / (1.0 + days[:, None] / half_lives[None, :])
-                )
-            if structure.suppress_bucket:
-                scores = scores + jnp.dot(matrix, q_sup, precision=_HIGHEST)
-            if structure.bias:
-                # hybrid lexical leg, sharded row-wise like the mask
-                scores = scores + bias
-            # one mask covers row-grid padding AND segment tombstones, so
-            # neither can ever enter the union with a real score; a panel
-            # structure shards one mask column PER PLAN instead
-            scores = jnp.where(mask if structure.panel else mask[:, None],
-                               scores, -jnp.inf)
-            k_local = min(structure.width, n_local)
-            v, i = jax.lax.top_k(scores.T, k_local)      # (B, k_local)
-            gi = i + shard * n_local                      # global row ids
-            if structure.mmr_k:
-                # shard-local MMR prefix: each shard gathers its OWN
-                # candidates' pool embeddings (an O(n_local) gather) and
-                # the payload merge ships them with the union — the MMR
-                # tail then never touches the replicated row space
-                pe = matrix[i]                            # (B, k_l, d)
-                return union_merge_topk_payload(v, gi, pe, ("shards",),
-                                                structure.width)
-            return union_merge_topk(v, gi, ("shards",), structure.width)
+            with jax.named_scope("score"):
+                scores = jnp.dot(matrix, q_pre, precision=_HIGHEST)
+                if structure.has_decay:
+                    scores = scores * (
+                        1.0 / (1.0 + days[:, None] / half_lives[None, :])
+                    )
+                if structure.suppress_bucket:
+                    scores = scores + jnp.dot(matrix, q_sup,
+                                              precision=_HIGHEST)
+                if structure.bias:
+                    # hybrid lexical leg, sharded row-wise like the mask
+                    scores = scores + bias
+                # one mask covers row-grid padding AND segment tombstones,
+                # so neither can ever enter the union with a real score; a
+                # panel structure shards one mask column PER PLAN instead
+                scores = jnp.where(mask if structure.panel
+                                   else mask[:, None], scores, -jnp.inf)
+            with jax.named_scope("select"):
+                k_local = min(structure.width, n_local)
+                v, i = jax.lax.top_k(scores.T, k_local)  # (B, k_local)
+                gi = i + shard * n_local                  # global row ids
+                if structure.mmr_k:
+                    # shard-local MMR prefix: each shard gathers its OWN
+                    # candidates' pool embeddings (an O(n_local) gather)
+                    # and the payload merge ships them with the union —
+                    # the MMR tail then never touches the replicated rows
+                    pe = matrix[i]                        # (B, k_l, d)
+                    return union_merge_topk_payload(v, gi, pe, ("shards",),
+                                                    structure.width)
+                return union_merge_topk(v, gi, ("shards",), structure.width)
 
         out_specs = ((P(None, None), P(None, None), P(None, None, None))
                      if structure.mmr_k else (P(None, None), P(None, None)))
@@ -1198,12 +1258,14 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                 # rode the exact top-k permutation the indices did
                 i, v, pe = inner(matrix, q_pre, q_sup, days, half_lives,
                                  mask, bias)
-                sel = _device_mmr_trace(pe, v, lams, pool_w,
-                                        structure.mmr_k)
-                i = jnp.take_along_axis(i, sel, axis=1)
-                v = jnp.take_along_axis(v, sel, axis=1)
-                keep = jnp.arange(structure.mmr_k)[None, :] < pool_w[:, None]
-                v = jnp.where(keep, v, -jnp.inf)
+                with jax.named_scope("mmr"):
+                    sel = _device_mmr_trace(pe, v, lams, pool_w,
+                                            structure.mmr_k)
+                    i = jnp.take_along_axis(i, sel, axis=1)
+                    v = jnp.take_along_axis(v, sel, axis=1)
+                    keep = (jnp.arange(structure.mmr_k)[None, :]
+                            < pool_w[:, None])
+                    v = jnp.where(keep, v, -jnp.inf)
             else:
                 i, v = inner(matrix, q_pre, q_sup, days, half_lives, mask,
                              bias)
@@ -1228,7 +1290,8 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
         return out[:n]
 
     def score_select(self, matrix, days_ago, plans, ks, *, mask=None,
-                     fused_mmr=None, score_bias=None, cohort=False):
+                     fused_mmr=None, score_bias=None, cohort=False,
+                     counters=None):
         for p in plans:
             _require_days(p, days_ago)
         n = matrix.shape[0]
@@ -1257,12 +1320,14 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
             live = np.zeros(padded, dtype=bool)
             live[:n] = True if mask is None else mask
         pool_w = _pool_widths(widths, mask, n, structure.batch)
-        mat = self._device_matrix(matrix, pad)
+        mat = self._device_matrix(matrix, pad, counters)
         # bias shards row-wise with the corpus grid; no-bias structures
         # take a replicated dummy the traced body never touches
         bias = (_expand_bias(score_bias, padded, structure.batch,
                              len(plans))
                 if structure.bias else np.zeros((1, 1), np.float32))
+        _count_uploads(counters, q_pre, q_sup, days, half_lives, live, lams,
+                       pool_w, bias)
         idx, vals = fn(mat, q_pre, q_sup, days, half_lives, live, lams,
                        pool_w, bias)
         out_w = ([min(max(k, 0), w) for k, w in zip(ks, widths)]
@@ -1510,7 +1575,7 @@ def score_select_segments(
             seg.matrix, seg.days_ago(now), plans,
             [min(k, n_el) for k in ks], fused_mmr=device_mmr,
             score_bias=None if score_bias is None else score_bias[i],
-            cohort=cohort)
+            cohort=cohort, counters=counters)
         if use_mmr and counters is not None:
             counters.device_mmr += sum(
                 1 for p, k in zip(plans, ks)
@@ -1533,7 +1598,7 @@ def score_select_segments(
         sel = backend.score_select(
             seg.matrix, seg.days_ago(now), seg_plans, widths, mask=m,
             score_bias=None if score_bias is None else score_bias[i],
-            cohort=cohort)
+            cohort=cohort, counters=counters)
         parts.append([(idx + offsets[i], vals) for idx, vals in sel])
 
     merged: List[Candidates] = []
@@ -1793,7 +1858,8 @@ def score_select_prefiltered(
     sub_bias = (None if score_bias is None
                 else _gather_bias(score_bias, segments, rows))
     sel = backend.score_select(sub, days, plans, ks_eff,
-                               fused_mmr=device_mmr, score_bias=sub_bias)
+                               fused_mmr=device_mmr, score_bias=sub_bias,
+                               counters=counters)
     # the gather arm pays resolve+gather+upload+score per candidate row
     router.record_gather((time.perf_counter() - t0) * 1e3, int(rows.size))
     if (counters is not None and backend.device_mmr

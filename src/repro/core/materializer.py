@@ -16,6 +16,12 @@ They are pseudo-functions recognized here, *before* SQLite sees the query:
 Failure mode is an explicit ``MaterializeError`` (the agent retries), never
 silent misexecution.
 
+With the span recorder (:mod:`repro.core.spans`) on, each phase records a
+span under the statement's: ``sql.phase1`` (the pre-filter SELECT),
+``sql.plan`` (parse + embed, FTS5 lookups included), ``sql.fts`` (one
+FTS5 query), ``sql.materialize`` (min-max, temp table, insert, snippet
+UPDATE) and ``sql.select`` (the rewritten statement's execute + fetch).
+
 Live-corpus ingest (the delta surface): ``INSERT INTO chunks ...`` and
 ``DELETE FROM chunks ...`` are recognized and routed — the row change
 applies to SQLite (``_raw_chunks`` + FTS5 sync), missing embeddings are
@@ -43,6 +49,7 @@ _TEMP_IDS = itertools.count(1)
 from repro.core import grammar
 from repro.core import modulations as M
 from repro.core.backends import ExecutionBackend, get_backend
+from repro.core.spans import RECORDER
 from repro.core.vectorcache import VectorCache
 
 # scanned case-insensitively; the canonical (lowercase) spelling is what
@@ -242,12 +249,13 @@ class Materializer:
         rewritten = self.rewrite(sql)
         if not _READONLY_RE.match(rewritten):
             raise MaterializeError("only read-only SELECT/WITH statements are allowed")
-        try:
-            cur = self.conn.execute(rewritten, params)
-        except sqlite3.Error as e:
-            raise MaterializeError(f"SQL error after rewrite: {e}") from e
-        cols = [d[0] for d in cur.description] if cur.description else []
-        return cols, cur.fetchall()
+        with RECORDER.span("sql.select"):
+            try:
+                cur = self.conn.execute(rewritten, params)
+            except sqlite3.Error as e:
+                raise MaterializeError(f"SQL error after rewrite: {e}") from e
+            cols = [d[0] for d in cur.description] if cur.description else []
+            return cols, cur.fetchall()
 
     def rewrite(self, sql: str) -> str:
         """Phases 1+2: materialize every pseudo-call, rewrite references."""
@@ -360,10 +368,12 @@ class Materializer:
         if prefilter_sql is not None and prefilter_sql.strip():
             if not _READONLY_RE.match(prefilter_sql):
                 raise MaterializeError(f"{kind} pre-filter must be a SELECT")
-            try:
-                rows = self.conn.execute(prefilter_sql).fetchall()
-            except sqlite3.Error as e:
-                raise MaterializeError(f"pre-filter SQL failed: {e}") from e
+            with RECORDER.span("sql.phase1"):
+                try:
+                    rows = self.conn.execute(prefilter_sql).fetchall()
+                except sqlite3.Error as e:
+                    raise MaterializeError(
+                        f"pre-filter SQL failed: {e}") from e
             candidate_ids = [r[0] for r in rows]
             if not candidate_ids:
                 # Paper §7: malformed pre-filters returning no rows are an
@@ -376,8 +386,9 @@ class Materializer:
                 return table
 
         try:
-            plan = None
-            if parsed is not None:
+            with RECORDER.span("sql.plan"):
+                if parsed is None:
+                    parsed = grammar.tokenize(tokens)
                 plan = grammar.build_plan(
                     parsed, self.cache.embed_fn,
                     self.cache.embeddings_for_ids, self._lexical_scores)
@@ -396,6 +407,11 @@ class Materializer:
         except Exception as e:  # grammar errors -> explicit failure
             raise MaterializeError(f"{kind} failed: {e}") from e
 
+        with RECORDER.span("sql.materialize"):
+            return self._result_table(kind, cols, results)
+
+    def _result_table(self, kind: str, cols: List[str],
+                      results: List[tuple]) -> str:
         # the unified result-row contract: score min-max normalized,
         # snippet after score, structural columns (§3.2) trailing
         if results:
@@ -591,8 +607,9 @@ class Materializer:
         ``limit`` comes from the plan's ``pool:`` width on the hybrid path
         (formerly a hardcoded 500 that silently truncated wide pools).
         """
-        return fts_query(self.conn, term, limit=limit,
-                         fts_table=self.fts_table)
+        with RECORDER.span("sql.fts"):
+            return fts_query(self.conn, term, limit=limit,
+                             fts_table=self.fts_table)
 
     def _lexical_scores(self, term: str, limit: int) -> Tuple[np.ndarray, np.ndarray]:
         """``grammar.LexicalFn``: keyword text + pool width -> BM25 hits.

@@ -57,10 +57,17 @@ The core is an **asyncio event loop** on a private thread:
   executor AND the store lock with the scoring pass, so it can never
   land inside one.
 
-Latency accounting uses ``time.monotonic()`` end to end, so an NTP step
-can't produce negative or inflated latencies.  ``close()`` drains the
-queue: every request not yet served fails with :class:`EngineClosedError`
-instead of hanging into its timeout.
+Latency accounting uses ``time.perf_counter()`` end to end — monotonic,
+so an NTP step can't produce negative or inflated latencies, and the
+clock of :mod:`repro.core.spans`.  With the span recorder on, every
+request records ``engine.request`` (enqueue to finish, under the caller's
+current span), ``engine.admit`` (the caller-thread admission) and
+``engine.queue`` (admitted to the start of its batch's device stage);
+every batch records ``engine.collect`` (the admission window),
+``engine.wait_device`` (the window held open on a busy device),
+``engine.device`` and ``engine.tail``, each listing its requests.
+``close()`` drains the queue: every request not yet served fails with
+:class:`EngineClosedError` instead of hanging into its timeout.
 
 Phase-1 filtered queries are first-class batch citizens: ``search`` /
 ``asearch`` take ``candidate_ids`` and the device stage groups requests
@@ -103,6 +110,7 @@ from repro.core.backends import (ExecutionBackend,
 from repro.core import modulations as M
 from repro.core.grammar import parse
 from repro.core.segments import CompactionPolicy
+from repro.core.spans import RECORDER, Span
 from repro.core.vectorcache import VectorCache
 
 __all__ = [
@@ -141,10 +149,15 @@ class Request:
     # construction on the CALLER's thread so identical filters from
     # different clients group into one scoring call at the device stage
     candidate_ids: Optional[np.ndarray] = None
-    # monotonic clock: NTP steps can't produce negative/inflated latencies
-    enqueued_at: float = dataclasses.field(default_factory=time.monotonic)
+    # perf_counter: monotonic (NTP steps can't produce negative/inflated
+    # latencies) and the span recorder's clock
+    enqueued_at: float = dataclasses.field(default_factory=time.perf_counter)
     latency_ms: float = 0.0
     plan: Optional[Any] = None         # parsed at admission (see _submit)
+    # recorder on: the open ``engine.request`` span, and when admission
+    # handed the request to the scheduler (``engine.queue`` starts there)
+    span: Optional[Span] = None
+    admitted_ns: int = 0
     seq: int = dataclasses.field(default_factory=lambda: next(_seq))
     future: "cf.Future[List[Tuple[int, float]]]" = dataclasses.field(
         default_factory=cf.Future)
@@ -180,10 +193,11 @@ class Request:
                 np.asarray(cand, dtype=np.int64))
             self._filter_key = self.candidate_ids.tobytes()
 
-    def expired(self, now_monotonic: float) -> bool:
+    def expired(self, now: float) -> bool:
+        """``now`` on ``time.perf_counter``, the clock of ``enqueued_at``."""
         if self.deadline_ms is None:
             return False
-        return (now_monotonic - self.enqueued_at) * 1e3 > self.deadline_ms
+        return (now - self.enqueued_at) * 1e3 > self.deadline_ms
 
 
 @dataclasses.dataclass
@@ -454,6 +468,24 @@ class BatchedRetrievalEngine:
     # -- admission -----------------------------------------------------------
 
     def _submit(self, req: Request) -> None:
+        if not RECORDER.on:
+            self._admit_on_caller(req)
+            return
+        req.span = RECORDER.open("engine.request", req.seq,
+                                 start_ns=int(req.enqueued_at * 1e9))
+        admit = RECORDER.open("engine.admit", parent=req.span)
+        try:
+            self._admit_on_caller(req)
+        except BaseException:
+            RECORDER.close(admit)
+            if not req.future.done():
+                RECORDER.close(req.span, admit.end_ns)
+            raise
+        # admission ends at the hand-off to the scheduler: engine.queue
+        # starts on the same timestamp
+        RECORDER.close(admit, req.admitted_ns)
+
+    def _admit_on_caller(self, req: Request) -> None:
         with self._admission_lock:
             if self._closed:
                 raise EngineClosedError("engine is closed")
@@ -503,6 +535,8 @@ class BatchedRetrievalEngine:
         except Exception:
             self._release_slot(req)
             raise
+        if req.span is not None:
+            req.admitted_ns = time.perf_counter_ns()
         try:
             self._loop.call_soon_threadsafe(self._admit, req)
         except RuntimeError:  # loop closed between the check and the call
@@ -612,6 +646,7 @@ class BatchedRetrievalEngine:
                 return []
         if self._closing:
             return []
+        t_open = time.perf_counter_ns() if RECORDER.on else 0
         start = self._loop.time()
         base_s = self.max_wait_ms / 1e3
         deadline = start + base_s
@@ -635,18 +670,21 @@ class BatchedRetrievalEngine:
                 return []
         if self.adaptive_window and self._loop.time() - start > base_s:
             self.windows_extended += 1
+        t_linger = time.perf_counter_ns() if t_open else 0
 
+        waited = False
         if self.async_dispatch:
             dev = self._dev_fut
             if dev is not None and not dev.done():
                 if self._pending:
                     self.overlapped_collects += 1
+                waited = True
                 try:
                     await dev  # arrivals keep appending while we wait
                 except Exception:
                     pass  # the completion chain fails that batch
 
-        now_mono = time.monotonic()
+        now = time.perf_counter()
         live: List[Request] = []
         expired: List[Request] = []
         with self._admission_lock:
@@ -657,7 +695,7 @@ class BatchedRetrievalEngine:
             for req in self._pending:
                 if req.future.done():
                     continue
-                (expired if req.expired(now_mono) else live).append(req)
+                (expired if req.expired(now) else live).append(req)
             live.sort(key=lambda r: (-r.priority, r.seq))
             batch, rest = live[:self.max_batch], live[self.max_batch:]
             self._depth -= len(batch) + len(expired)
@@ -671,6 +709,12 @@ class BatchedRetrievalEngine:
             self._fail(req, DeadlineExceededError(
                 f"deadline of {req.deadline_ms:.1f} ms passed before the "
                 f"request reached a batch"), count_depth=False)
+        if t_open and batch:
+            ids = [req.seq for req in batch]
+            RECORDER.emit("engine.collect", t_open, t_linger, requests=ids)
+            if waited:
+                RECORDER.emit("engine.wait_device", t_linger,
+                              int(now * 1e9), requests=ids)
         return batch
 
     async def _idle_maintenance(self) -> None:
@@ -805,6 +849,24 @@ class BatchedRetrievalEngine:
             self._tail_running = False
 
     def _device_stage(self, batch: List[Request]) -> Optional[_TailWork]:
+        with RECORDER.span("engine.device") as sp:
+            if sp is None:
+                return self._device_pass(batch, None)
+            for req in batch:
+                if req.span is not None:
+                    RECORDER.emit("engine.queue", req.admitted_ns,
+                                  sp.start_ns, parent=req.span)
+            counters = self.cache.fused
+            up = counters.upload_bytes
+            sp.attrs["requests"] = [req.seq for req in batch]
+            sp.attrs["arms"] = []
+            try:
+                return self._device_pass(batch, sp.attrs["arms"])
+            finally:
+                sp.attrs["upload_bytes"] = counters.upload_bytes - up
+
+    def _device_pass(self, batch: List[Request],
+                     arms: Optional[List[str]]) -> Optional[_TailWork]:
         """One fused backend pass: fold every request's (admission-parsed)
         plan into the (d, B) panels and run the segment-aware
         ``score_select_segments`` — every segment is scored ONCE for the
@@ -835,6 +897,8 @@ class BatchedRetrievalEngine:
 
         ref = self.now if self.now is not None else time.time()
         if self.shard_group is not None:
+            if arms is not None:
+                arms.append("shards")
             # shard-router fan-out: the whole collected batch goes to one
             # replica per shard as ONE plan cohort (heterogeneous filters
             # ride each shard's mask panel) and comes back merged + final
@@ -888,6 +952,8 @@ class BatchedRetrievalEngine:
                           else int(live[idxs[0]].candidate_ids.size)
                           for key, idxs in groups.items()]
                 if router.use_panel(counts, n_live):
+                    if arms is not None:
+                        arms.append("panel")
                     # heterogeneous-filter cohort: ONE batched (N, B)
                     # mask-panel pass for the whole batch instead of one
                     # pass per distinct filter — unfiltered requests ride
@@ -906,6 +972,13 @@ class BatchedRetrievalEngine:
                         # additive score panel (None when the group has
                         # no weighted-fusion plans — the common case)
                         g_bias = fusion_bias_arrays(store, segs, g_plans)
+                        if arms is not None:
+                            arms.append(
+                                "cohort" if key is None
+                                else "masked" if router.use_masked(
+                                    int(live[idxs[0]].candidate_ids.size),
+                                    n_live)
+                                else "gather")
                         if key is None:
                             # the batch IS a cohort: one fused (d, 2·Q)
                             # panel per segment pass, pow2 Q-bucketed on
@@ -943,6 +1016,12 @@ class BatchedRetrievalEngine:
         mid-loop would let those parses convoy against the remaining MMR
         work.  Delivered at the end, the wake-up storm lands during the
         next batch's GIL-releasing device pass instead."""
+        with RECORDER.span("engine.tail") as sp:
+            if sp is not None:
+                sp.attrs["requests"] = [req.seq for req in work.requests]
+            self._finish_work(work)
+
+    def _finish_work(self, work: _TailWork) -> None:
         if work.final:
             # shard-group results arrive final (diversity + fusion done at
             # the coordinator, pool-width like the direct path): hand back k
@@ -976,9 +1055,16 @@ class BatchedRetrievalEngine:
 
     # -- completion ----------------------------------------------------------
 
+    def _complete(self, req: Request) -> None:
+        """Latency on the ``enqueued_at`` clock; closes ``engine.request``."""
+        end = time.perf_counter_ns()
+        req.latency_ms = (end * 1e-9 - req.enqueued_at) * 1e3
+        if req.span is not None:
+            RECORDER.close(req.span, end)
+
     def _fail(self, req: Request, err: Exception, *,
               count_depth: bool = True) -> None:
-        req.latency_ms = (time.monotonic() - req.enqueued_at) * 1e3
+        self._complete(req)
         if count_depth:
             self._release_slot(req)
         try:
@@ -987,7 +1073,7 @@ class BatchedRetrievalEngine:
             pass
 
     def _finish(self, req: Request, result: List[Tuple[int, float]]) -> None:
-        req.latency_ms = (time.monotonic() - req.enqueued_at) * 1e3
+        self._complete(req)
         self.requests_served += 1
         try:
             req.future.set_result(result)

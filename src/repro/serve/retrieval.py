@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core.backends import ExecutionBackend, get_backend
 from repro.core.materializer import MaterializeError, Materializer
+from repro.core.spans import RECORDER
 from repro.core.vectorcache import VectorCache
 from repro.embed import HashEmbedder
 from repro.sqlio.presets import run_preset
@@ -108,9 +109,18 @@ class RetrievalService:
         ``params`` are standard SQLite positional bind parameters for the
         (rewritten) statement — same contract as ``Materializer.execute``,
         so parameterized SQL no longer needs a hand-built Materializer.
+        ``latency_ms`` is on ``time.perf_counter``, the span clock; with
+        the recorder on the statement records ``sql.statement``, parent of
+        every span the statement opens.
         """
-        t0 = time.time()
+        t0 = time.perf_counter()
         self.query_count += 1
+        with RECORDER.span("sql.statement"):
+            result = self._flex_search(query, params)
+        result.latency_ms = (time.perf_counter() - t0) * 1e3
+        return result
+
+    def _flex_search(self, query: str, params: Sequence) -> SearchResult:
         try:
             if query.strip().startswith("@"):
                 name = query.strip().split()[0]
@@ -119,18 +129,15 @@ class RetrievalService:
                 cols = ["section", "data"]
                 for key, (c, r) in out.items():
                     rows.append((key, {"columns": c, "rows": r}))
-                return SearchResult(True, cols, rows,
-                                    latency_ms=(time.time() - t0) * 1e3)
+                return SearchResult(True, cols, rows)
             mz = Materializer(self.conn, self.cache, now=self.now,
                               engine=self.engine, serving=self._serving)
             cols, rows = mz.execute(query, params)
-            return SearchResult(True, cols, rows,
-                                latency_ms=(time.time() - t0) * 1e3)
+            return SearchResult(True, cols, rows)
         except (MaterializeError, sqlite3.Error, KeyError) as e:
             # explicit failure -> the agent rewrites and retries (paper §7)
             self.error_count += 1
-            return SearchResult(False, error=f"{type(e).__name__}: {e}",
-                                latency_ms=(time.time() - t0) * 1e3)
+            return SearchResult(False, error=f"{type(e).__name__}: {e}")
 
     def search(
         self,
