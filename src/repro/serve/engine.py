@@ -34,7 +34,11 @@ The core is an **asyncio event loop** on a private thread:
   so bursty closed-loop load keeps folding into one cohort while a lone
   request closes its window as soon as arrivals quiesce, instead of the
   fixed ``max_wait_ms`` fragmenting cohorts (``adaptive_window=False``
-  restores the fixed window exactly).
+  restores the fixed window exactly).  Only a real cadence is a sample:
+  an arrival that finds every earlier request delivered (its future
+  set) is a BURST START — its delta is a lone client's own round trip,
+  not a cadence — so the learned gap is cleared and that window lingers
+  the static base; ``burst_starts`` counts them.
 * **pipeline** — one device pass and one host tail may be in flight at
   once (two single-thread executors); ``overlapped_batches`` counts
   batches whose device pass ran while the previous tail was still
@@ -275,6 +279,7 @@ class BatchedRetrievalEngine:
         self.overlapped_collects = 0  # admission windows held open on a
         #                               busy device (async dispatch)
         self.windows_extended = 0    # adaptive windows that outlingered base
+        self.burst_starts = 0        # arrivals that found nothing undelivered
         self.compactions_run = 0     # idle-gap compactions that folded
         self.vectorizer_drains = 0   # idle-gap vectorizer batches ingested
 
@@ -282,6 +287,11 @@ class BatchedRetrievalEngine:
         self._queued: Dict[int, Request] = {}  # seq -> queued request, the
         #                              shedding candidate set (admission lock)
         self._admission_lock = threading.Lock()
+        # seqs admitted whose future is not yet set: an arrival that finds
+        # none besides itself starts a burst (see _admit).  Its own lock:
+        # _fail runs under the admission lock when it sheds a victim
+        self._undelivered: set = set()
+        self._undelivered_lock = threading.Lock()
         self._closed = False         # no new admissions (set by close())
         self._closing = False        # loop-confined shutdown flag
         self._done = threading.Event()
@@ -458,6 +468,7 @@ class BatchedRetrievalEngine:
             "overlapped_batches": self.overlapped_batches,
             "overlapped_collects": self.overlapped_collects,
             "windows_extended": self.windows_extended,
+            "burst_starts": self.burst_starts,
             "window_ms": round(self._window_s() * 1e3, 3),
             "async_dispatch": self.async_dispatch,
             "adaptive_window": self.adaptive_window,
@@ -517,6 +528,8 @@ class BatchedRetrievalEngine:
             else:
                 self._depth += 1  # slot reserved before the (costly) parse
             self._queued[req.seq] = req
+            with self._undelivered_lock:
+                self._undelivered.add(req.seq)
         try:
             if req.plan is not None:
                 # pre-parsed plan handed over (materializer path): skip
@@ -545,10 +558,17 @@ class BatchedRetrievalEngine:
 
     def _release_slot(self, req: Request) -> None:
         """Free one admission slot and drop the request from the shedding
-        candidate set (no-op on the latter if collect already took it)."""
+        candidate set (no-op on the latter if collect already took it)
+        and from the undelivered set."""
         with self._admission_lock:
             self._depth -= 1
             self._queued.pop(req.seq, None)
+            self._delivered(req)
+
+    def _delivered(self, req: Request) -> None:
+        """Drop ``req`` from the undelivered set; idempotent."""
+        with self._undelivered_lock:
+            self._undelivered.discard(req.seq)
 
     def _parse(self, req: Request):
         plan = parse(req.tokens, self.cache.embed_fn,
@@ -563,12 +583,21 @@ class BatchedRetrievalEngine:
 
     def _window_s(self) -> float:
         """Current admission-window linger in seconds: the static base, or
-        the learned quiescence gap clamped to [0.05 ms, 4·base]."""
+        the learned quiescence gap clamped to [0.05 ms, 4·base].  A burst
+        start clears the gap, so its window lingers the base."""
         if not self.adaptive_window or self._gap_ms is None:
             return self.max_wait_ms / 1e3
         return min(max(self._gap_ms, 0.05), self.max_wait_ms * 4) / 1e3
 
     def _admit(self, req: Request) -> None:  # loop thread
+        """Hand an admitted request to the scheduler and learn the cadence.
+
+        An arrival that finds no EARLIER request undelivered is a burst
+        start (``burst_starts``): its delta from the last arrival is a
+        lone client's own round trip, not a cadence — a closed-loop client
+        cannot send again before its answer — so it is no sample and the
+        learned gap is cleared (this window lingers the base).  Any other
+        arrival's delta is an EWMA sample unless it exceeds the hard cap."""
         if self._closing:
             self._fail(req, EngineClosedError(
                 "engine closed before the request was served"))
@@ -576,7 +605,12 @@ class BatchedRetrievalEngine:
         t = self._loop.time()
         last = self._last_arrival_t
         self._last_arrival_t = t
-        if self.adaptive_window and last is not None:
+        with self._undelivered_lock:
+            others = len(self._undelivered) - (req.seq in self._undelivered)
+        if not others:
+            self.burst_starts += 1
+            self._gap_ms = None
+        elif self.adaptive_window and last is not None:
             delta_ms = (t - last) * 1e3
             # a gap past the hard cap is a NEW burst, not a cadence
             # sample — folding it in would freeze the window wide open
@@ -1056,7 +1090,10 @@ class BatchedRetrievalEngine:
     # -- completion ----------------------------------------------------------
 
     def _complete(self, req: Request) -> None:
-        """Latency on the ``enqueued_at`` clock; closes ``engine.request``."""
+        """Latency on the ``enqueued_at`` clock; closes ``engine.request``;
+        leaves the undelivered set BEFORE the future wakes the client, so
+        a closed-loop client's next arrival finds nothing undelivered."""
+        self._delivered(req)
         end = time.perf_counter_ns()
         req.latency_ms = (end * 1e-9 - req.enqueued_at) * 1e3
         if req.span is not None:

@@ -618,3 +618,178 @@ def test_fixed_window_mode_reports_base():
         assert eng.windows_extended == 0
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# burst starts: a lone client's own round trip is not a cadence
+# ---------------------------------------------------------------------------
+
+
+class SlowFused(FusedNumpyBackend):
+    """A fused pass that takes ~7.5 ms, so a lone request's round trip at
+    the 2 ms base window lands inside the 8·base cadence cut-off (16 ms):
+    a chip-like request time on the CPU."""
+
+    name = "slow-fused"
+
+    def score_select(self, *args, **kwargs):
+        time.sleep(0.0075)
+        return super().score_select(*args, **kwargs)
+
+
+def _sequential(eng, n=10):
+    for i in range(n):
+        assert len(eng.search(f"similar:group {i % 7} tail", 3,
+                              timeout=10.0)) == 3
+
+
+def test_lone_client_window_stays_at_base():
+    cache, _ = make_cache()
+    eng = BatchedRetrievalEngine(cache, max_wait_ms=2.0, engine="fused")
+    try:
+        _sequential(eng)
+        st = eng.stats()
+        # every arrival found its predecessor delivered: no cadence sample
+        assert st["burst_starts"] == 10
+        assert st["window_ms"] == 2.0
+        assert len(eng._undelivered) == 0
+    finally:
+        eng.close()
+
+
+def test_lone_client_round_trip_does_not_freeze_window():
+    """The regression: a lone request's own latency (8–16 ms here) used to
+    be folded in as the cadence, widening every later window to its
+    8 ms cap; later round trips past 16 ms were dropped as new bursts,
+    so the window never came back."""
+    cache, _ = make_cache()
+    eng = BatchedRetrievalEngine(cache, max_wait_ms=2.0, engine=SlowFused())
+    try:
+        _sequential(eng)
+        st = eng.stats()
+        assert st["window_ms"] == 2.0
+        assert st["burst_starts"] == 10
+        assert eng.batches_served == 10
+    finally:
+        eng.close()
+
+
+def _gated_burst(eng, gate, n):
+    """One request in the gated device pass, then ``n`` concurrent
+    arrivals while it is undelivered; returns every answer."""
+    with cf.ThreadPoolExecutor(n + 1) as ex:
+        first = ex.submit(eng.search, "similar:group 0 tail", 3)
+        assert gate.entered.wait(5.0)
+        rest = [ex.submit(eng.search, f"similar:group {i % 7} tail", 3)
+                for i in range(1, n + 1)]
+        # every arrival reached the scheduler (not only admission) while
+        # the first is still undelivered
+        assert wait_for(lambda: len(eng._pending) == n)
+        assert len(eng._undelivered) == n + 1
+        gate.release.set()
+        return [f.result(10.0) for f in [first] + rest]
+
+
+def test_concurrent_burst_learns_gap_then_lone_search_resets():
+    cache, _ = make_cache()
+    gate = GateBackend()
+    eng = BatchedRetrievalEngine(cache, max_batch=64, max_wait_ms=2.0,
+                                 engine=gate)
+    try:
+        assert all(len(r) == 3 for r in _gated_burst(eng, gate, 7))
+        # arrivals 2..8 found an undelivered predecessor: cadence samples
+        assert eng._gap_ms is not None
+        assert eng.burst_starts == 1
+        assert len(eng._undelivered) == 0
+        assert len(eng.search("similar:group 3 tail", 3)) == 3
+        st = eng.stats()
+        assert st["burst_starts"] == 2
+        assert st["window_ms"] == 2.0
+    finally:
+        gate.release.set()
+        eng.close()
+
+
+def test_concurrent_burst_still_folds_into_cohorts():
+    cache, _ = make_cache()
+    gate = GateBackend()
+    eng = BatchedRetrievalEngine(cache, max_batch=64, max_wait_ms=2.0,
+                                 engine=gate)
+    try:
+        assert all(len(r) == 3 for r in _gated_burst(eng, gate, 16))
+        st = eng.stats()
+        assert st["requests_served"] == 17
+        assert st["requests_served"] > st["batches_served"]
+        assert st["burst_starts"] == 1
+        assert eng._gap_ms is not None
+        assert 0.05 <= st["window_ms"] <= 8.0
+    finally:
+        gate.release.set()
+        eng.close()
+
+
+@pytest.mark.parametrize("path", ["rejected", "shed", "expired",
+                                  "admission_error", "backend_error"])
+def test_undelivered_returns_to_zero(path):
+    cache, _ = make_cache()
+
+    class OnceFailing(GateBackend):
+        boom = path == "backend_error"
+
+        def score_select(self, *args, **kwargs):
+            if OnceFailing.boom:
+                OnceFailing.boom = False
+                raise RuntimeError("injected device failure")
+            return super().score_select(*args, **kwargs)
+
+    gate = OnceFailing(released=path == "backend_error")
+    eng = BatchedRetrievalEngine(cache, max_batch=1, engine=gate, max_queue=2)
+    try:
+        if path == "backend_error":
+            with pytest.raises(RuntimeError, match="injected"):
+                eng.search("similar:group 1 tail", 5, timeout=10.0)
+            assert len(eng._undelivered) == 0
+            assert len(eng.search("similar:group 2 tail", 5)) == 5
+        elif path == "admission_error":
+            with pytest.raises(Exception):
+                eng.search("decay:zzz", 5)
+            assert len(eng._undelivered) == 0
+            assert eng.queue_depth == 0
+        else:
+            with cf.ThreadPoolExecutor(4) as ex:
+                blocker = ex.submit(eng.search, "similar:group 1 tail", 5)
+                assert gate.entered.wait(5.0)
+                kw = {"deadline_ms": 20.0} if path == "expired" else {}
+                queued = []
+                for i in (2, 3):  # one at a time: seq order is shed order
+                    queued.append(ex.submit(eng.search,
+                                            f"similar:group {i} tail", 5,
+                                            10.0, **kw))
+                    assert wait_for(lambda: eng.queue_depth == i - 1)
+                assert len(eng._undelivered) == 3
+                if path == "rejected":
+                    with pytest.raises(QueueFullError):
+                        eng.search("similar:group 4 tail", 5)
+                elif path == "shed":
+                    high = ex.submit(eng.search, "similar:group 4 tail", 5,
+                                     priority=5)
+                    with pytest.raises(QueueFullError):
+                        queued[-1].result(10.0)  # newest of the lowest
+                    queued = queued[:-1] + [high]
+                else:
+                    time.sleep(0.1)  # let the 20 ms deadlines lapse
+                gate.release.set()
+                assert len(blocker.result(10.0)) == 5
+                for f in queued:
+                    if path == "expired":
+                        with pytest.raises(DeadlineExceededError):
+                            f.result(10.0)
+                    else:
+                        assert len(f.result(10.0)) == 5
+            counter = {"rejected": "rejected", "shed": "shed_low_priority",
+                       "expired": "deadline_misses"}[path]
+            assert eng.stats()[counter] >= 1
+        assert wait_for(lambda: len(eng._undelivered) == 0)
+    finally:
+        gate.release.set()
+        eng.close()
