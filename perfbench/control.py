@@ -3,11 +3,12 @@
 
     python3 perfbench/control.py --workload h1m_search_single --seeds 11 12 13
 
-For each seed it builds the cell's corpus, draws the window's requests as a
-run would (through the traffic's generator and request modules),
-samples them as a run's check does, answers them with the reference
-computed from bfloat16 rows and queries (float32 accumulation), and holds
-those answers to the float64 reference with the cell's comparison.  A
+For each seed it builds the cell's corpus through the configuration's
+corpus module, draws the window's requests as a run would (through the
+traffic's generator and request modules), samples them as a run's check
+does, answers them with the module's reference computed from bfloat16 rows
+and queries (float32 accumulation), and holds those answers to the float64
+reference with the cell's comparison.  A
 number the control reads is an upper reading for that number's limit;
 the benchmark's own runs never run this.  It needs no chip.
 """
@@ -26,9 +27,7 @@ if str(ROOT) not in sys.path:
 
 from perfbench.lib import check, drive  # noqa: E402
 from perfbench.lib.bench import Benchmark  # noqa: E402
-from perfbench.lib.corpus import generate  # noqa: E402
-from perfbench.lib.embedding import HashEmbedding  # noqa: E402
-from perfbench.lib.reference import Reference, answers  # noqa: E402
+from perfbench.lib.reference import answers  # noqa: E402
 
 
 def control_numbers(workload: str, seed: int, seconds: float = 20.0,
@@ -38,14 +37,16 @@ def control_numbers(workload: str, seed: int, seconds: float = 20.0,
     cell = bench.workload(workload)
     cfg = dict(bench.config(cell["config"]), **(config_overrides or {}))
     traffic = bench.traffic(cell["traffic"])
-    emb = HashEmbedding(int(cfg["dim"]))
-    corpus = generate(cfg, seed, emb)
+    corpus_mod = bench.corpus(cfg)
+    emb = corpus_mod.embedding(cfg)
+    corpus = corpus_mod.generate(cfg, seed, emb)
     specs = bench.generator(traffic).window_requests(traffic, seed, seconds,
                                                      bench.requests(traffic))
     records = [{"spec": s} for s in specs]
     specs = [r["spec"] for r in drive.sample(records, int(traffic["check_sample"]), seed)]
-    got = answers(Reference(corpus, emb, precision="bf16"), specs)
-    return check.compare_all(Reference(corpus, emb), specs, [got[j] for j in range(len(specs))])
+    got = answers(corpus_mod.reference(corpus, emb, "bf16"), specs)
+    return check.compare_all(corpus_mod.reference(corpus, emb, "f64"), specs,
+                             [got[j] for j in range(len(specs))])
 
 
 def main() -> None:

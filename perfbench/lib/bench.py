@@ -1,7 +1,9 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 - a configuration: its entry's ``file`` (``perfbench/configs/<name>.json``),
-  whose ``system`` names ``perfbench/systems/<system>.py``;
+  whose ``system`` names ``perfbench/systems/<system>.py`` (the deployment
+  under test) and whose ``corpus`` names ``perfbench/corpora/<corpus>.py``
+  (its data, the embedding its queries share, and the plain reference);
 - a traffic mix: ``perfbench/traffic/<traffic>.json``, whose ``generator``
   names ``perfbench/traffic/<generator>.py`` (the arrivals) and whose
   ``requests`` names ``perfbench/requests/<requests>.py`` (what is sent);
@@ -9,7 +11,10 @@
 - a metric: ``perfbench/metrics/<name>.py`` with ``read(run)``.
 
 A new configuration, mix, arrival pattern, request kind, cell or metric is
-new files and a new entry in ``BENCHMARK.json``; nothing here changes.
+new files and a new entry in ``BENCHMARK.json``; nothing here changes.  A
+new deployment is a corpus module, a configuration that names it, and,
+where its query text is not agent-history words (``lib/traffic.text``
+draws only those), a request kind of its own that draws its text.
 """
 
 from __future__ import annotations
@@ -82,6 +87,15 @@ class Benchmark:
 
     def system_path(self, system: str) -> Path:
         return self.root / "perfbench" / "systems" / f"{system}.py"
+
+    def corpus_path(self, corpus: str) -> Path:
+        return self.root / "perfbench" / "corpora" / f"{corpus}.py"
+
+    def corpus(self, cfg: dict) -> ModuleType:
+        """The configuration's corpus module (``embedding``, ``generate``,
+        ``reference``)."""
+        name = cfg["corpus"]
+        return load_module(self.corpus_path(name), f"perfbench_corpus_{name}")
 
     def metric_path(self, name: str) -> Path:
         return self.root / "perfbench" / "metrics" / f"{name}.py"

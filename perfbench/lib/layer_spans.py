@@ -1,10 +1,10 @@
 """Per-layer numbers from the program's own spans (``repro.core.spans``).
 
-A traced run that turns the program's span recorder on for the window
-hands its spans to the readers as ``run.spans`` and the device time per
-``jax.named_scope`` stage as ``run.scope_seconds``; ``perfbench/run.py``
-does neither yet (PERF.md, section 7), so every reader here returns None
-on a run without them, as on a program that records no spans.
+A traced run turns the program's span recorder on for the window and
+hands its spans to the readers as ``run.spans``, and the device time per
+``jax.named_scope`` stage in the traced slice as ``run.scope_seconds``
+(``perfbench/run.py``); an untraced run has neither, and every reader here
+returns None on it, as on a program that records no spans.
 
 Spans are on ``time.perf_counter_ns``, the clock of the request records
 and of the trace's clock marker, so they meet the device's busy intervals
@@ -82,8 +82,7 @@ def idle_by_span(trace: tracing.Trace, records: Sequence[dict], spans) -> Dict[s
 def _ready(run, surface: str) -> Optional[List[dict]]:
     """The requests answered in the slice, or None where nothing can be
     read (no trace, no spans, another surface, nothing answered)."""
-    if (run.trace is None or getattr(run, "spans", None) is None
-            or run.surface != surface):
+    if run.trace is None or run.spans is None or run.surface != surface:
         return None
     return layers.finished_in_trace(run) or None
 
@@ -145,8 +144,8 @@ def statement_queue_ms(run) -> Optional[float]:
 def scope_ms_per_request(run, surface: str, scope: str) -> Optional[float]:
     """Device op time under one ``named_scope`` stage, per request."""
     done = _ready(run, surface)
-    scopes = getattr(run, "scope_seconds", None)
-    if done is None or not scopes or scope not in scopes:
+    scopes = run.scope_seconds or {}
+    if done is None or scope not in scopes:
         return None
     return scopes[scope] / len(done) * 1e3
 

@@ -3,14 +3,16 @@
 
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-In order: build the cell's corpus from the seed; bring up the served path
-the configuration names; warm every batch shape the cell's traffic forms
+In order: build the cell's corpus from the seed, through the corpus module
+the configuration names; bring up the served path the configuration names;
+warm every batch shape the cell's traffic forms
 (the persistent compilation cache lives in ``.jax_cache`` inside this
 checkout); drive the traffic for ``--seconds``; check a seeded sample of
 the window's answers against the plain reference; print one JSON result
 as the last line of standard output.  ``--trace 1`` traces a few seconds
-of the window and reports the per-layer metrics instead of the end-to-end
-ones.
+of the window, records the program's spans over the window
+(``repro.core.spans``), and reports the per-layer metrics instead of the
+end-to-end ones.
 
 Exits non-zero with no result line when JAX finds no TPU, or fewer chips
 than the cell asks for.  Everything runs in this one process: a chip
@@ -44,11 +46,10 @@ CACHE_DIR = ROOT / ".jax_cache"
 
 import jax  # noqa: E402
 
-from perfbench.lib import check, drive, measure, tracing, traffic as traffic_mod  # noqa: E402
+from repro.core.spans import RECORDER, Span  # noqa: E402
+
+from perfbench.lib import check, drive, layer_spans, measure, tracing, traffic as traffic_mod  # noqa: E402
 from perfbench.lib.bench import Benchmark, load_module, read_metrics  # noqa: E402
-from perfbench.lib.corpus import generate  # noqa: E402
-from perfbench.lib.embedding import HashEmbedding  # noqa: E402
-from perfbench.lib.reference import Reference  # noqa: E402
 
 TRACE_START, TRACE_SECONDS = 0.3, 4.0
 
@@ -65,7 +66,10 @@ def enable_compile_cache() -> None:
 
 @dataclasses.dataclass
 class Run:
-    """What a metric reader sees of one run."""
+    """What a metric reader sees of one run: ``counters`` is the system's
+    ``counters()`` after the window; a traced run adds the reduced
+    ``trace``, the program's ``spans`` recorded over the window and the
+    device seconds per stage scope in the traced slice, ``scope_seconds``."""
 
     workload: str
     surface: str
@@ -75,7 +79,10 @@ class Run:
     summary: Dict[str, float]
     records: List[dict]
     device: Dict[str, object]
+    counters: Dict[str, object]
     trace: Optional[tracing.Trace] = None
+    spans: Optional[List[Span]] = None
+    scope_seconds: Optional[Dict[str, float]] = None
 
 
 def log(**fields) -> None:
@@ -94,11 +101,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     traffic = bench.traffic(cell["traffic"])
     limits = bench.limits(workload)
     system_mod = load_module(bench.system_path(cfg["system"]), f"perfbench_system_{cfg['system']}")
+    corpus_mod = bench.corpus(cfg)
     generator, requests = bench.generator(traffic), bench.requests(traffic)
 
     t_build = time.perf_counter()
-    embedding = HashEmbedding(int(cfg["dim"]))
-    corpus = generate(cfg, seed, embedding)
+    embedding = corpus_mod.embedding(cfg)
+    corpus = corpus_mod.generate(cfg, seed, embedding)
     t_gen = time.perf_counter() - t_build
     clock = measure.CompileClock()
     with clock.watch():
@@ -119,12 +127,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     tracer = (tracing.Tracer(trace_dir, seconds * TRACE_START, min(TRACE_SECONDS, seconds * 0.4))
               if trace else None)
     inside = measure.CompileClock()
+    reduced = scope_seconds = None
+    if trace:
+        RECORDER.drain()  # a reader sees the window's spans and no others
+        RECORDER.on = True
     try:
-        with inside.watch():
-            records, t0, close = generator.drive_window(traffic, seed, seconds, requests, system,
-                                                        tracer.begin if tracer else None)
-        reduced = tracer.join() if tracer else None
+        try:
+            with inside.watch():
+                records, t0, close = generator.drive_window(traffic, seed, seconds, requests,
+                                                            system, tracer.begin if tracer else None)
+        finally:
+            RECORDER.on = False
+        spans = RECORDER.drain() if trace else None
         if tracer:
+            reduced = tracer.join()
+            scope_seconds = layer_spans.scope_seconds(
+                layer_spans.read_scoped_profile(tracer.path, tracer.marker_pc_ns), reduced.window)
             log(event="trace_layout", planes=tracing.profile_layout(tracer.path))
     finally:
         if trace_dir:
@@ -143,13 +161,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t_check = time.perf_counter()
     sampled = drive.sample(records, int(traffic["check_sample"]), seed)
     numbers = getattr(requests, "compare_all", check.compare_all)(
-        Reference(corpus, embedding), [r["spec"] for r in sampled],
+        corpus_mod.reference(corpus, embedding, "f64"), [r["spec"] for r in sampled],
         [r["answer"] if r["error"] is None else None for r in sampled])
     correct = check.verdict(numbers, limits) and bool(sampled)
     log(event="check", sampled=len(sampled), check_s=round(time.perf_counter() - t_check, 3))
 
     run = Run(workload, requests.SURFACE, cfg, traffic, setup_s, summary, records, device,
-              reduced)
+              counters, reduced, spans, scope_seconds)
     kind = "per_layer" if trace else "end_to_end"
     result = {"correct": correct, "attempted": summary["attempted"],
               "failed": summary["failed"],
@@ -157,8 +175,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
               "device": device}
     if reduced is not None:
         result["device"] = dict(device, busy_s=reduced.busy_s, window_s=reduced.window_s)
+        idle = layer_spans.idle_by_span(reduced, records, spans)
         result["breakdown"] = {"device_ops": tracing.top_ops(reduced),
-                               "idle_gaps": tracing.name_gaps(reduced, records)}
+                               "idle_gaps": tracing.name_gaps(reduced, records),
+                               "idle_by_span": sorted(([k, v] for k, v in idle.items()),
+                                                      key=lambda kv: -kv[1])[:10]}
     result["checks"] = {k: {"value": numbers[k], "limit": limits[k]} for k in check.NUMBERS}
     return result
 

@@ -26,8 +26,6 @@ import numpy as np  # noqa: E402
 
 from perfbench.lib import drive, measure, traffic as traffic_mod  # noqa: E402
 from perfbench.lib.bench import Benchmark, load_module  # noqa: E402
-from perfbench.lib.corpus import generate  # noqa: E402
-from perfbench.lib.embedding import HashEmbedding  # noqa: E402
 
 
 def main() -> None:
@@ -43,9 +41,10 @@ def main() -> None:
     device = measure.platform_or_exit(1)
     cfg = bench.config(args.config)
     traffic = bench.traffic(args.traffic)
-    emb = HashEmbedding(int(cfg["dim"]))
+    corpus_mod = bench.corpus(cfg)
+    emb = corpus_mod.embedding(cfg)
     system = load_module(bench.system_path(cfg["system"]), "sweep_system").System(
-        cfg, generate(cfg, args.seed, emb), emb)
+        cfg, corpus_mod.generate(cfg, args.seed, emb), emb)
     generator, requests = bench.generator(traffic), bench.requests(traffic)
     requests.warm(system, traffic, traffic_mod.warm_requests(traffic, args.seed, requests))
     try:

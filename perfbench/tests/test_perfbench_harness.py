@@ -1,7 +1,9 @@
 """The harness on the CPU: every name in BENCHMARK.json resolves to its
-files, a new arrival pattern is a new file and nothing else, the
-generators keep their schedules and time from due time, and a run refuses
-any platform but a TPU."""
+files, a new arrival pattern or a new deployment is new files and nothing
+else, the corpus module gives what the direct calls gave, a traced run
+hands its spans to the readers and leaves the recorder off, the generators
+keep their schedules and time from due time, and a run refuses any
+platform but a TPU."""
 
 import asyncio
 import json
@@ -15,9 +17,14 @@ import time
 import numpy as np
 import pytest
 
+from repro.core.spans import RECORDER
+
 from perfbench import run as R
-from perfbench.lib import drive
+from perfbench.lib import corpus as C
+from perfbench.lib import drive, traffic as traffic_mod
 from perfbench.lib.bench import ROOT, Benchmark
+from perfbench.lib.embedding import HashEmbedding
+from perfbench.lib.reference import Reference, answers
 
 BENCH = Benchmark()
 DOC = BENCH.doc
@@ -39,6 +46,8 @@ def test_configuration_resolves(cfg):
     assert cfg["file"].startswith("perfbench/") and (ROOT / cfg["file"]).is_file()
     body = BENCH.config(cfg["name"])
     assert body["name"] == cfg["name"] and BENCH.system_path(body["system"]).is_file()
+    mod = BENCH.corpus(body)
+    assert all(callable(getattr(mod, f)) for f in ("embedding", "generate", "reference"))
     assert any(w["config"] == cfg["name"] for w in DOC["workloads"])
 
 
@@ -180,6 +189,212 @@ def test_a_new_arrival_pattern_is_new_files_only(tmp_path):
     assert res["correct"], res["checks"]
     assert res["attempted"] == 16 and res["failed"] == 0
     assert {"search_p95_ms", "search_qps", "setup_s"} <= set(res["metrics"])
+
+
+TINY = {"rows": 3_000, "sessions": 50}
+
+
+def cpu(chips):
+    return {"platform": "cpu", "kind": "cpu", "count": chips}
+
+
+#: a deployment the benchmark does not have: seeded unit rows at 768-d with
+#: no timestamps, its own embedding over a 768-d parent space, and its own
+#: reference (plain cosine top-k in float64, or bfloat16 for the control)
+UNIT_CORPUS = '''
+import dataclasses
+
+import ml_dtypes
+import numpy as np
+
+from perfbench.lib.embedding import HashEmbedding, normalize_rows
+from perfbench.lib.reference import Scored, top_rows, unit
+
+
+@dataclasses.dataclass
+class Corpus:
+    matrix: np.ndarray
+    timestamps = None
+
+    @property
+    def n(self):
+        return int(self.matrix.shape[0])
+
+    @property
+    def ids(self):
+        return np.arange(self.n, dtype=np.int64)
+
+
+def embedding(cfg):
+    return HashEmbedding(int(cfg["dim"]), full_dim=int(cfg["dim"]))
+
+
+def generate(cfg, seed, embedding):
+    rng = np.random.default_rng([seed, 0])
+    return Corpus(normalize_rows(rng.standard_normal((int(cfg["rows"]), embedding.dim),
+                                                     dtype=np.float32)))
+
+
+class Reference:
+    def __init__(self, corpus, embedding, precision):
+        self.corpus, self.embedding, self.precision = corpus, embedding, precision
+
+    def _vec(self, v):
+        if self.precision == "bf16":
+            return np.asarray(v, np.float32).astype(ml_dtypes.bfloat16).astype(np.float32)
+        return np.asarray(v, np.float64)
+
+    def rows(self, ids):
+        return self._vec(self.corpus.matrix[np.asarray(ids, np.int64)])
+
+    def score(self, specs):
+        q = np.stack([unit(self.embedding(s["similar"])) for s in specs], axis=1)
+        dots = self._vec(self.corpus.matrix) @ self._vec(q)
+        return [Scored(dots[:, j], self.corpus.n) for j in range(len(specs))]
+
+    def answer(self, spec, scored):
+        s = scored.scores
+        return [(int(i), float(s[i])) for i in top_rows(s, spec["k"])]
+
+
+def reference(corpus, embedding, precision):
+    return Reference(corpus, embedding, precision)
+'''
+
+#: a request kind that draws its own query text (seeded tokens, none of
+#: them agent-history words) and serves it through the search surface
+UNIT_REQUESTS = '''
+from perfbench.lib.traffic import base_spec
+from perfbench.requests.search import call, submit, warm  # noqa: F401
+
+SURFACE = "search"
+
+
+def make(rng, entry, traffic):
+    spec = base_spec(entry, SURFACE, traffic["k"])
+    spec["similar"] = " ".join(f"t{int(x)}" for x in rng.integers(0, 5000, size=4))
+    spec["tokens"] = spec["text"] = f"similar:{spec['similar']}"
+    return spec
+'''
+
+#: per-layer metrics of the new cell: one reads the system's counters after
+#: the window, one the program's spans recorded over it
+UNIT_COUNTER_METRIC = '''
+def read(run):
+    engine = run.counters.get("engine") or {}
+    served = engine.get("requests_served")
+    return served / engine["batches_served"] if served else None
+'''
+UNIT_SPAN_METRIC = '''
+def read(run):
+    if run.spans is None:
+        return None
+    ms = [(sp.end_ns - sp.start_ns) * 1e-6 for sp in run.spans if sp.name == "engine.request"]
+    return sum(ms) / len(ms) if ms else None
+'''
+
+
+def test_a_new_deployment_is_new_files_only(tmp_path):
+    """A copy of the benchmark gains a 768-d corpus with no timestamps, its
+    configuration, a request kind with its own query text, a mix, limits, a
+    cell and two per-layer metrics (counters and spans): all new files plus
+    entries, and a traced tiny run of it on the CPU reads correct and
+    reports both metrics."""
+    root = tmp_path / "bench"
+    pkg = root / "perfbench"
+    shutil.copytree(ROOT / "perfbench", pkg, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    before = {p: p.read_bytes() for p in pkg.rglob("*") if p.is_file()}
+    new = {
+        "corpora/unit_rows.py": UNIT_CORPUS,
+        "requests/unit_search.py": UNIT_REQUESTS,
+        "metrics/requests_per_batch.unit.py": UNIT_COUNTER_METRIC,
+        "metrics/request_ms.unit.py": UNIT_SPAN_METRIC,
+        "configs/unit_768.json": json.dumps({
+            "name": "unit_768", "corpus": "unit_rows", "system": "engine", "engine": "jit-jax",
+            "max_batch": 8, "rows": 2_000, "dim": 768, "now": 1_770_000_000}),
+        "traffic/unit_closed4.json": json.dumps({
+            "source": "a test", "generator": "closed_loop", "requests": "unit_search",
+            "clients": 4, "k": 10, "check_sample": 16, "mix": [{"name": "plain", "weight": 1}]}),
+    }
+    for rel, text in new.items():
+        assert not (pkg / rel).exists()
+        (pkg / rel).write_text(text)
+    shutil.copy(BENCH.limits_path("h1m_search_closed64"), pkg / "limits" / "unit_768_closed4.json")
+    doc = json.loads(json.dumps(DOC))
+    doc["configs"].append({"name": "unit_768", "source": "a test", "reduced": [],
+                           "file": "perfbench/configs/unit_768.json", "why": "a test"})
+    doc["workloads"].append({"name": "unit_768_closed4", "config": "unit_768",
+                             "traffic": "unit_closed4", "chips": 1, "why": "a test"})
+    for m in doc["end_to_end"]:
+        if "h1m_search_closed64" in m.get("workloads", ()):
+            m["workloads"].append("unit_768_closed4")
+    doc["per_layer"] += [
+        {"name": n, "unit": u, "better": "lower", "source": src, "layer": "a test",
+         "moves": "search_p95_ms", "workloads": ["unit_768_closed4"]}
+        for n, u, src in (("requests_per_batch.unit", "requests", "program_counter"),
+                          ("request_ms.unit", "ms", "program_span"))]
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    assert all(p.read_bytes() == b for p, b in before.items())  # nothing edited
+
+    res = R.run_cell("unit_768_closed4", 2**31 + 29, 1.0, True, device_check=cpu,
+                     bench=Benchmark(root))
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert set(res["metrics"]) == {"requests_per_batch.unit", "request_ms.unit"}
+    assert res["metrics"]["requests_per_batch.unit"]["value"] >= 1.0
+    assert res["metrics"]["request_ms.unit"]["value"] > 0.0
+    assert not RECORDER.on
+
+
+@pytest.mark.parametrize("seed", [5, 2**31 + 77])
+@pytest.mark.parametrize("config", ["agent_history_1m", "agent_history_240k"])
+def test_agent_history_module_gives_the_direct_calls(config, seed):
+    """The configuration's corpus module gives, bit for bit, what the
+    harness built before it named one: the corpus, the requests of every
+    cell on it, and the reference's answers on a sample of them."""
+    cfg = dict(BENCH.config(config), **TINY)
+    mod = BENCH.corpus(cfg)
+    emb = mod.embedding(cfg)
+    got = mod.generate(cfg, seed, emb)
+    emb0 = HashEmbedding(int(cfg["dim"]))
+    want = C.generate(cfg, seed, emb0)
+    for f in ("matrix", "timestamps", "words", "ctype", "project", "session", "topic"):
+        assert getattr(got, f).tobytes() == getattr(want, f).tobytes(), f
+    cells = [w for w in DOC["workloads"] if w["config"] == config]
+    assert cells
+    for cell in cells:
+        traffic = BENCH.traffic(cell["traffic"])
+        req = BENCH.requests(traffic)
+        warm = traffic_mod.warm_requests(traffic, seed, req)
+        specs = BENCH.generator(traffic).window_requests(traffic, seed, 1.0, req)
+        specs = [r["spec"] for r in drive.sample([{"spec": s} for s in specs], 6, seed)]
+        specs += list(warm.values())
+        for precision in ("f64", "bf16"):
+            a = answers(mod.reference(got, emb, precision), specs)
+            b = answers(Reference(want, emb0, precision=precision), specs)
+            assert a == b, (cell["name"], precision)
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
+def test_recorder_is_off_after_a_run(trace):
+    """A traced run records the window's spans and hands them to the span
+    readers; an untraced one never turns the recorder on (no span site
+    records); neither leaves it on."""
+    RECORDER.drain()
+    res = R.run_cell("h1m_search_single", 2**31 + 41, 1.0, trace, device_check=cpu,
+                     config_overrides=TINY)
+    assert res["correct"], res["checks"]
+    assert not RECORDER.on
+    spans = {"admit_ms_per_query.search", "queue_ms_per_query.search",
+             "dispatch_ms_per_query.search", "upload_bytes_per_query.search",
+             "tail_ms_per_query.search"}
+    if trace:
+        assert spans <= set(res["metrics"])
+        assert res["metrics"]["upload_bytes_per_query.search"]["value"] > 0
+        assert "idle_by_span" in res["breakdown"]
+    else:
+        assert RECORDER.drain() == []
+        assert not spans & set(res["metrics"]) and "breakdown" not in res
 
 
 def test_open_loop_times_from_due_and_reports_lateness():

@@ -71,8 +71,7 @@ def test_search_readers():
 
 
 def test_readers_find_nothing_without_spans():
-    run = FakeRun("search", None)
-    del run.spans, run.scope_seconds  # a run as perfbench/run.py makes it now
+    run = FakeRun("search", None)  # an untraced run: no spans, no scopes
     assert all(BENCH.reader(m).read(run) is None for m in SEARCH + SQL)
     run = FakeRun("search", search_spans())
     run.trace = None
