@@ -47,10 +47,10 @@ equivalence suites (tests/test_backends.py, tests/test_score_select.py)
 stay anchored to the reference oracle.  Device backends compile through a
 :class:`PlanCache` (LRU-bounded) keyed on :class:`PlanStructure` — plan
 *shape* (batch width, decay present/absent, suppress count bucketed by
-padding, top-k width AND corpus row count bucketed to powers of two) — so
-distinct query texts with the same structure never retrigger tracing, and
-a stream of varying corpus/segment sizes compiles one graph per pow2
-bucket, not one per exact row count.
+padding, top-k width bucketed to powers of two, corpus row count padded
+to its row grid) — so distinct query texts with the same structure never
+retrigger tracing, and a stream of varying corpus/segment sizes compiles
+one graph per grid step, not one per exact row count.
 
 Live corpora (`repro.core.segments`) score through
 :func:`score_select_segments`: each segment scores independently (its
@@ -120,6 +120,23 @@ def _pow2_bucket(x: int) -> int:
     if x <= 0:
         return 0
     return 1 << (x - 1).bit_length()
+
+
+#: the device row grid doubles up to this many rows, then grows in steps
+#: of ``ROW_GRID_STEP`` (a multiple of 4 shards x 8 sublanes)
+ROW_GRID_POW2_MAX = 1 << 20
+ROW_GRID_STEP = 1 << 17
+
+
+def _row_grid(n_rows: int) -> int:
+    """The device row count a corpus of ``n_rows`` pads to: the pow2
+    bucket at or below ``ROW_GRID_POW2_MAX`` rows (segments and Phase-1
+    sub-corpora of every size share a few traces), above it the next
+    multiple of ``ROW_GRID_STEP`` (8,841,823 rows pad to 8,912,896, not
+    to 16,777,216: the padding is zeros that every scan reads)."""
+    if n_rows <= ROW_GRID_POW2_MAX:
+        return max(_pow2_bucket(n_rows), 1)
+    return -(-n_rows // ROW_GRID_STEP) * ROW_GRID_STEP
 
 
 def _half_lives(plans: Sequence[M.ModulationPlan]) -> np.ndarray:
@@ -324,14 +341,14 @@ class PlanStructure:
 
     Two batches with the same structure lower to the same specialized
     graph: query texts, embedding values, and half-life magnitudes are
-    runtime data, never trace constants.  Suppress count, top-k width AND
-    the corpus row count are bucketed (padded up to powers of two) so the
-    number of distinct traces stays bounded as requests — and segment
-    sizes — vary.
+    runtime data, never trace constants.  Suppress count and top-k width
+    are bucketed (padded up to powers of two) and the corpus row count is
+    padded to its row grid (:func:`_row_grid`), so the number of distinct
+    traces stays bounded as requests — and segment sizes — vary.
     """
 
     batch: int            # B — number of plans folded into the panel
-    n_rows: int           # DEVICE row count: corpus rows pow2-bucketed
+    n_rows: int           # DEVICE row count: the corpus rows' row grid
     has_decay: bool       # decay factor branch present in the graph
     suppress_bucket: int  # max suppress count, padded to a power of two
     width: int            # static top-k width (pow2-bucketed, <= n_rows)
@@ -343,11 +360,14 @@ class PlanStructure:
     # only 0-vs-nonzero changes the lowered graph (the second matmul drops
     # out); the pow2 buckets keep the key future-proof for unfused panel
     # formulations where the direction count IS a shape.  NOTE on n_rows:
-    # it is the pow2 ROW BUCKET — device backends zero-pad the corpus up
-    # to it and mask the padding to -inf, so Phase-1 pre-filtered
-    # sub-corpora and store segments of varying size share one compiled
-    # executable per bucket instead of one per exact row count (the
-    # per-segment PlanCache would otherwise grow with every append).
+    # it is the ROW GRID (:func:`_row_grid`) — device backends zero-pad
+    # the corpus up to it and mask the padding to -inf, so Phase-1
+    # pre-filtered sub-corpora and store segments of varying size share
+    # one compiled executable per grid step instead of one per exact row
+    # count (the per-segment PlanCache would otherwise grow with every
+    # append).  Up to 2^20 rows the grid is the pow2 bucket; above it a
+    # doubling would leave up to half of device memory and of every scan
+    # to padding, so the grid grows in 2^17-row steps there.
 
     # NOTE on mmr_k/panel: the diverse-on-device tail (a fori_loop of
     # mmr_k steps) and the 2-D mask panel change the lowered graph, so
@@ -377,7 +397,7 @@ class PlanStructure:
         never sliced out into results)."""
         max_sup = max((len(p.suppress) for p in plans), default=0)
         w = max(widths, default=0)
-        bucket = max(_pow2_bucket(n_rows), 1)
+        bucket = _row_grid(n_rows)
         width = min(max(_pow2_bucket(w), 1), bucket)
         mmr_k = 0
         if device_mmr and ks is not None and any(
@@ -483,6 +503,9 @@ class _DeviceMatrixMixin:
     a warm 240k corpus uploads ONLY the new segment while every sealed
     segment stays device-resident.  ``uploads`` counts host->device
     copies; tests pin the only-the-delta ingest contract on it.
+    :meth:`device_cache_stats` also gives ``rows_placed``, the true rows
+    of the resident arrays, and ``row_grid``, the device rows that hold
+    them (padding included, across the mesh for the sharded backend).
     """
 
     _DEV_CACHE_SIZE = 32
@@ -491,12 +514,13 @@ class _DeviceMatrixMixin:
     dev_hits = 0       # calls served from the resident cache
     dev_evictions = 0  # LRU evictions
 
-    def _place(self, mat: np.ndarray):
-        """Host -> device copy of one (padded) corpus matrix; the sharded
-        backend overrides this to place rows across its mesh."""
+    def _place(self, mat: np.ndarray, pad: int):
+        """Host -> device copy of one corpus matrix with ``pad`` zero rows
+        below it; the sharded backend overrides this to place each row
+        slice on its own device."""
         import jax
 
-        return jax.device_put(mat)
+        return jax.device_put(np.pad(mat, ((0, pad), (0, 0))) if pad else mat)
 
     def _device_matrix(self, matrix: np.ndarray, pad: int = 0,
                        counters: Optional[FusedCounters] = None):
@@ -510,10 +534,9 @@ class _DeviceMatrixMixin:
             self.dev_hits += 1
             return entry[1]
         mat = np.asarray(matrix, np.float32)
-        if pad:
-            mat = np.pad(mat, ((0, pad), (0, 0)))
-        dev = self._place(mat)
-        _count_uploads(counters, mat)
+        dev = self._place(mat, pad)
+        if counters is not None:
+            counters.upload_bytes += (mat.shape[0] + pad) * mat.shape[1] * mat.itemsize
         cache[key] = (matrix, dev)
         cache.move_to_end(key)
         self.uploads += 1
@@ -537,11 +560,14 @@ class _DeviceMatrixMixin:
         return self._device_matrix(matrix)
 
     def device_cache_stats(self) -> Dict[str, int]:
+        resident = list(self.__dict__.get("_dev_cache", {}).values())
         return {
-            "entries": len(self.__dict__.get("_dev_cache", ())),
+            "entries": len(resident),
             "uploads": self.uploads,
             "hits": self.dev_hits,
             "evictions": self.dev_evictions,
+            "rows_placed": sum(int(src.shape[0]) for src, _ in resident),
+            "row_grid": sum(int(dev.shape[0]) for _, dev in resident),
         }
 
 
@@ -1153,20 +1179,38 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                 axis_types=(AxisType.Auto,))
         return self._shards_mesh
 
-    def _place(self, mat: np.ndarray):
-        """Upload the corpus row-sharded over the mesh, so each device
+    def _place(self, mat: np.ndarray, pad: int):
+        """Place the corpus row-sharded over the mesh, so each device
         holds its own 1/shards of the rows and ``shard_map`` never
-        reshards it per call.  Row sharding needs a shard multiple: the
-        scoring paths pad to one already, and any extra zero rows here sit
-        past the true row count, where nothing indexes."""
+        reshards it per call.  Each device receives a view of its own row
+        slice: no host copy of the whole matrix is made.  Only the slice
+        that reaches past the true rows is copied, to end in the zero
+        rows of ``pad`` and of the shard multiple (past the true row
+        count, where nothing indexes).  The slices go one at a time, each
+        transfer finished before the next starts: the runtime stages a
+        host copy of what it is sent, and all shards sent at once would
+        stage the whole matrix again."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        n, d = mat.shape
         mesh = self._mesh()
-        extra = (-mat.shape[0]) % mesh.size
-        if extra:
-            mat = np.pad(mat, ((0, extra), (0, 0)))
-        return jax.device_put(mat, NamedSharding(mesh, P("shards", None)))
+        rows = n + pad
+        rows += (-rows) % mesh.size
+        sharding = NamedSharding(mesh, P("shards", None))
+
+        def shard(index):
+            lo, hi, _ = index[0].indices(rows)
+            if hi <= n:
+                return mat[lo:hi]
+            out = np.zeros((hi - lo, d), mat.dtype)
+            out[:max(n - lo, 0)] = mat[lo:n]
+            return out
+
+        parts = []
+        for device, index in sharding.addressable_devices_indices_map((rows, d)).items():
+            parts.append(jax.device_put(shard(index), device).block_until_ready())
+        return jax.make_array_from_single_device_arrays((rows, d), sharding, parts)
 
     def _build(self):
         import jax
@@ -1230,9 +1274,11 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                     # and the payload merge ships them with the union —
                     # the MMR tail then never touches the replicated rows
                     pe = matrix[i]                        # (B, k_l, d)
-                    return union_merge_topk_payload(v, gi, pe, ("shards",),
-                                                    structure.width)
-                return union_merge_topk(v, gi, ("shards",), structure.width)
+            # the cross-chip union merge names its own ``merge`` scope
+            if structure.mmr_k:
+                return union_merge_topk_payload(v, gi, pe, ("shards",),
+                                                structure.width)
+            return union_merge_topk(v, gi, ("shards",), structure.width)
 
         out_specs = ((P(None, None), P(None, None), P(None, None, None))
                      if structure.mmr_k else (P(None, None), P(None, None)))
@@ -1306,8 +1352,8 @@ class ShardedBackend(_DeviceMMRMixin, _DeviceMatrixMixin, ExecutionBackend):
                                      bias=score_bias is not None,
                                      cohort=cohort)
         fn = self.plan_cache.get(structure)
-        # row grid: pow2 bucket (the PlanCache key), then up to a shard
-        # multiple — derived from the bucket alone, so one trace per bucket
+        # row grid (the PlanCache key), then up to a shard multiple —
+        # derived from the grid alone, so one trace per grid step
         padded = structure.n_rows + ((-structure.n_rows) % n_shards)
         pad = padded - n
         q_pre, q_sup, half_lives, lams = _panel_inputs(plans, structure,

@@ -59,16 +59,19 @@ def union_merge_topk(
     top-k, so the merge is exact.
 
     Shared by :func:`make_pem_topk` and the ``sharded`` ExecutionBackend's
-    fused ``score_select`` stage (repro/core/backends.py).
+    fused ``score_select`` stage (repro/core/backends.py).  Its ops run
+    under the ``merge`` stage scope, so a device trace reads the merge
+    (all-gathers and union top-k) apart from the shard-local selection.
     """
-    cand_v = jax.lax.all_gather(v, axes)              # (shards, B, k_l)
-    cand_i = jax.lax.all_gather(gi, axes)
-    b = v.shape[0]
-    union = cand_v.shape[0] * cand_v.shape[-1]        # shards * k_local
-    cand_v = jnp.swapaxes(cand_v, 0, 1).reshape(b, union)
-    cand_i = jnp.swapaxes(cand_i, 0, 1).reshape(b, union)
-    vk, pos = jax.lax.top_k(cand_v, min(k, union))
-    ik = jnp.take_along_axis(cand_i, pos, axis=1)
+    with jax.named_scope("merge"):
+        cand_v = jax.lax.all_gather(v, axes)          # (shards, B, k_l)
+        cand_i = jax.lax.all_gather(gi, axes)
+        b = v.shape[0]
+        union = cand_v.shape[0] * cand_v.shape[-1]    # shards * k_local
+        cand_v = jnp.swapaxes(cand_v, 0, 1).reshape(b, union)
+        cand_i = jnp.swapaxes(cand_i, 0, 1).reshape(b, union)
+        vk, pos = jax.lax.top_k(cand_v, min(k, union))
+        ik = jnp.take_along_axis(cand_i, pos, axis=1)
     return ik, vk
 
 
@@ -93,20 +96,21 @@ def union_merge_topk_payload(
     ``pk[b, j] == matrix[ik[b, j]]`` element-for-element and any
     consumer (the fused MMR tail) sees bit-identical inputs to the
     replicated-gather formulation.  Returns ``(indices, values,
-    payload)``, each (B, min(k, union), ...).
+    payload)``, each (B, min(k, union), ...), under the ``merge`` scope.
     """
-    cand_v = jax.lax.all_gather(v, axes)              # (shards, B, k_l)
-    cand_i = jax.lax.all_gather(gi, axes)
-    cand_p = jax.lax.all_gather(pe, axes)             # (shards, B, k_l, d)
-    b = v.shape[0]
-    union = cand_v.shape[0] * cand_v.shape[-1]        # shards * k_local
-    d = cand_p.shape[-1]
-    cand_v = jnp.swapaxes(cand_v, 0, 1).reshape(b, union)
-    cand_i = jnp.swapaxes(cand_i, 0, 1).reshape(b, union)
-    cand_p = jnp.swapaxes(cand_p, 0, 1).reshape(b, union, d)
-    vk, pos = jax.lax.top_k(cand_v, min(k, union))
-    ik = jnp.take_along_axis(cand_i, pos, axis=1)
-    pk = jnp.take_along_axis(cand_p, pos[..., None], axis=1)
+    with jax.named_scope("merge"):
+        cand_v = jax.lax.all_gather(v, axes)          # (shards, B, k_l)
+        cand_i = jax.lax.all_gather(gi, axes)
+        cand_p = jax.lax.all_gather(pe, axes)         # (shards, B, k_l, d)
+        b = v.shape[0]
+        union = cand_v.shape[0] * cand_v.shape[-1]    # shards * k_local
+        d = cand_p.shape[-1]
+        cand_v = jnp.swapaxes(cand_v, 0, 1).reshape(b, union)
+        cand_i = jnp.swapaxes(cand_i, 0, 1).reshape(b, union)
+        cand_p = jnp.swapaxes(cand_p, 0, 1).reshape(b, union, d)
+        vk, pos = jax.lax.top_k(cand_v, min(k, union))
+        ik = jnp.take_along_axis(cand_i, pos, axis=1)
+        pk = jnp.take_along_axis(cand_p, pos[..., None], axis=1)
     return ik, vk, pk
 
 

@@ -98,3 +98,33 @@ def test_jit_jax_select_graph_compiles_for_v5e(one_chip, n_rows):
         s(one_chip, (n_rows,), jnp.bool_), s(one_chip, (b,)),
         s(one_chip, (b,), jnp.int32), s(one_chip, (1, 1))).compile()
     _fits_one_chip(compiled)
+
+
+def test_sharded_select_graph_compiles_for_four_v5e_chips(topo):
+    """The passage deployment's fullest graph on a 2x2 mesh: 8,841,823
+    768-d rows on their row grid (8,912,896, a quarter a chip), a cohort of
+    32 with a suppress direction, the 2,048-wide pool of a diverse search
+    and its MMR tail.  Each chip holds a quarter of the rows, and the whole
+    program fits one chip's memory."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.backends import ShardedBackend, _row_grid
+
+    rows, d, b = _row_grid(8_841_823), 768, 32
+    backend = ShardedBackend()
+    backend._shards_mesh = Mesh(topo.devices, ("shards",))
+    structure = PlanStructure(batch=b, n_rows=rows, has_decay=False,
+                              suppress_bucket=1, width=2048, mmr_k=16)
+    fn = backend._build_select(structure)
+
+    def s(shape, spec, dtype=jnp.float32):
+        return _spec(NamedSharding(backend._shards_mesh, spec), shape, dtype)
+
+    compiled = fn.lower(
+        s((rows, d), P("shards", None)), s((d, b), P()), s((d, b), P()),
+        s((rows,), P("shards")), s((b,), P()), s((rows,), P("shards"), jnp.bool_),
+        s((b,), P()), s((b,), P(), jnp.int32), s((1, 1), P())).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= rows // 4 * d * 4
+    _fits_one_chip(compiled)
+    assert "all-gather" in compiled.as_text()
